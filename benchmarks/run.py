@@ -16,6 +16,7 @@ from benchmarks import (bench_fig4_tradeoff, bench_fig5_convergence,
                         bench_serve_ingest, bench_sim_scale,
                         bench_table2_energy, bench_table3_overhead)
 from benchmarks.common import emit
+from repro.launch.cache import enable_compile_cache
 
 BENCHES = [
     ("table2", bench_table2_energy),
@@ -39,6 +40,7 @@ def main() -> int:
                     help="comma-separated bench names")
     args = ap.parse_args()
     only = set(args.only.split(",")) if args.only else None
+    enable_compile_cache()
 
     failures = 0
     for name, mod in BENCHES:

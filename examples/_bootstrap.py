@@ -9,7 +9,9 @@ The documented invocation stays canonical::
 With the prefix set (or the package installed) this helper is a no-op; the
 fallback resolves ``src/`` relative to this file, so it also works from any
 working directory — unlike the old per-script ``sys.path.insert(0, "src")``
-hack, which silently broke outside the repo root.
+hack, which silently broke outside the repo root. It also turns on JAX's
+persistent compilation cache (``repro.launch.cache``) before the example's
+first compile.
 """
 import os
 import sys
@@ -28,3 +30,7 @@ def ensure_repro_on_path() -> None:
 
 
 ensure_repro_on_path()
+
+from repro.launch.cache import enable_compile_cache  # noqa: E402
+
+enable_compile_cache()

@@ -16,10 +16,10 @@ heuristics and stop scaling past ~1M users:
   Under-estimates stay safe: the driver detects buffer overflow by count
   and re-runs the chunk doubled.
 
-Budgets come from the accelerator's ``memory_stats()`` when the backend
-reports one (GPU/TPU ``bytes_limit``), else system RAM split over the
-(possibly forced-host) device count — so the same tuner sizes a CPU
-smoke test and a TPU pod run.
+Budgets come from the accelerator's ``memory_stats()`` (GPU/TPU
+``bytes_limit``; an accelerator without one is an error) and, on the CPU
+backend only, from system RAM split over the (possibly forced-host)
+device count — so the same tuner sizes a CPU smoke test and a TPU run.
 """
 from __future__ import annotations
 
@@ -56,24 +56,24 @@ def _prev_pow2(k: int) -> int:
 
 def device_memory_budget(n_devices: int = 1, fraction: float = 0.25) -> int:
     """Usable bytes per device for the scan's operands: the device's
-    reported ``bytes_limit`` when the backend exposes ``memory_stats()``
-    (GPU/TPU), else system RAM split over the ``n_devices`` host devices.
+    reported ``bytes_limit`` on an accelerator, system RAM split over the
+    ``n_devices`` host devices on the CPU backend. An accelerator that
+    reports no limit is an error, never sized from host RAM.
     ``fraction`` leaves headroom for XLA temporaries, the replicated
     scalars and the rest of the process."""
     import jax
 
-    limit = None
-    try:
-        stats = jax.local_devices()[0].memory_stats() or {}
-        limit = stats.get("bytes_limit")
-    except Exception:           # CPU backends raise / return nothing
-        limit = None
-    if not limit:
-        try:
-            limit = (os.sysconf("SC_PAGE_SIZE")
-                     * os.sysconf("SC_PHYS_PAGES")) // max(int(n_devices), 1)
-        except (ValueError, OSError, AttributeError):
-            limit = 4 << 30     # no sysconf (non-POSIX): assume 4 GiB
+    dev = jax.local_devices()[0]
+    if dev.platform == "cpu":
+        limit = (os.sysconf("SC_PAGE_SIZE")
+                 * os.sysconf("SC_PHYS_PAGES")) // max(int(n_devices), 1)
+    else:
+        limit = (dev.memory_stats() or {}).get("bytes_limit")
+        if not limit:
+            raise RuntimeError(
+                f"{dev.platform} device {dev.device_kind!r} reports no "
+                "memory_stats()['bytes_limit']; pass mem_bytes or set "
+                "jax_chunk explicitly")
     return int(limit * fraction)
 
 

@@ -484,22 +484,48 @@ class OnlinePolicy(Policy):
         lag_idx = sv.in_flight + jnp.arange(sv.n + 1)
         gap_vec = _jax_gradient_gap(vn, lag_idx, sv.eta, sv.beta)
 
-        def fast(_):
-            # H == 0: the gap term adds exactly 0 to both branches.
+        def commit(sched):
             # sv.repl pins `sched` replicated: it has a sharded consumer
             # in the engine (begin-training), and without the pin GSPMD
             # propagates that layout back through cumsum/gather/where and
             # turns the gap_sum below into reassociated shard-local
             # partials + AllReduce (the reduce(all-gather) -> all-reduce
             # rewrite), flipping low bits of the Eq. 16 H update
-            sched = sv.repl(waiting & (base <= rhs))
+            sched = sv.repl(sched)
             before = jnp.cumsum(sched) - sched
-            gaps = jnp.where(sched, gap_vec[before], gap_idle_v)
+            gaps = jnp.where(waiting, jnp.where(sched, gap_vec[before],
+                                                gap_idle_v), 0.0)
             # sum the LIVE lanes only ([:sv.n] folds to a no-op when the
             # sharded scan hasn't padded the user axis): pad lanes never
             # wait, and excluding their zeros keeps the reduction tree —
             # hence the Eq. 16 H update — bit-identical to unsharded
-            return sched, jnp.sum(jnp.where(waiting, gaps, 0.0)[:sv.n])
+            return sched, jnp.sum(gaps[:sv.n])
+
+        def fast(_):
+            # H == 0: the gap term adds exactly 0 to both branches
+            return commit(waiting & (base <= rhs))
+
+        def cost_le(g):
+            return base + (H * g + sv.fp_zero) \
+                <= rhs + (H * gap_idle_v + sv.fp_zero)
+
+        # H > 0: user i sees in-slot lag j = #scheduled before it, with j
+        # below the waiting count. The cost is monotone in gap_vec[j], so a
+        # user that passes at the largest reachable gap passes at every j
+        # and one that fails at the smallest fails at every j: when no
+        # waiting user lies between, the decisions are order-free and equal
+        # the sequential replay's. Only a user in between needs the replay,
+        # which on an accelerator costs one loop step per user. The Eq. 16
+        # sum is then a tree, as in the NumPy engine's decide_batch, so H
+        # matches the replay's left fold to rounding.
+        reach = jnp.arange(sv.n + 1) < jnp.sum(waiting)
+        g_min = jnp.min(jnp.where(reach, gap_vec, jnp.inf))
+        g_max = jnp.max(jnp.where(reach, gap_vec, -jnp.inf))
+        always = waiting & cost_le(g_max)
+        coupled = jnp.any(waiting & cost_le(g_min) & ~always)
+
+        def order_free(_):
+            return commit(always)
 
         def slow(_):
             # sequential in-slot lag coupling, user-index order
@@ -516,7 +542,8 @@ class OnlinePolicy(Policy):
                 (waiting, base, rhs, gap_idle_v))
             return sched, gs
 
-        start, gap_sum = lax.cond(H > 0.0, slow, fast, None)
+        branch = jnp.where(H > 0.0, jnp.where(coupled, 2, 1), 0)
+        start, gap_sum = lax.switch(branch, (fast, order_free, slow), None)
         sv.idle_gap = jnp.where(waiting & ~start,
                                 sv.idle_gap + sv.epsilon, sv.idle_gap)
         return carry, (start, gap_sum)

@@ -308,6 +308,8 @@ class SimResult:
     corun_fraction: float
     drops: int = 0                  # mid-training dropouts (device churn;
     #                                 0 with dynamics="none")
+    placement: Optional[dict] = None  # jax scan: leaf name -> (device ids,
+    #                                 shard shape) of the final carry
 
 
 # UserState.mode string <-> shared engine code (engine_state constants);

@@ -1108,7 +1108,12 @@ def _run_jax(sim) -> SimResult:
     if not policy.supports_jax or \
             not dynamics_support(dynamics)["jax"] or \
             (cfg.collect_push_log and not aggregation_support(agg)["jax"]):
-        return _NumpyEngine(sim).run()  # resolve_engine reroutes; be safe
+        # resolve_engine degrades such runs before they get here; running
+        # the host engine under the jax name would hide the device
+        raise ValueError(
+            f"the jax scan cannot run policy {policy.name!r} with dynamics "
+            f"{dynamics.name!r} and aggregation {agg.name!r}; "
+            "sim.resolve_engine() picks the engine that can")
     # sharded run: resolve the ("users",) mesh first — the auto-tuner and
     # the user-axis padding both need the LIVE device count. A 1-device
     # mesh degenerates to the plain path (identical graph, no constraint
@@ -1175,6 +1180,13 @@ def _run_jax(sim) -> SimResult:
         e_parts.append(np.asarray(esum, dtype=float)[:m])
         ci += 1
 
+    # where the scan's per-user carry and arrival operands actually lived
+    placement = {
+        name: (tuple(sorted(d.id for d in x.sharding.device_set)),
+               x.sharding.shard_shape(x.shape))
+        for name, x in (("state.mode", state.mode),
+                        ("state.energy", state.energy),
+                        ("app_sched", rs.app_sched))}
     # the run's final state, readable on the host like the other engines'
     host = _state_to_host(state, jax)
     if mesh is not None and n_arr != n:
@@ -1205,7 +1217,8 @@ def _run_jax(sim) -> SimResult:
         mean_Q=sum_Q / T if T else 0.0,
         mean_H=sum_H / T if T else 0.0,
         corun_fraction=corun_updates / max(updates_total, 1),
-        drops=dynamics.total_drops(sim.state.dyn))
+        drops=dynamics.total_drops(sim.state.dyn),
+        placement=placement)
 
 
 # ======================================================================

@@ -10,7 +10,5 @@ ref.py pure-jnp oracle, validated via interpret=True on CPU):
                         the ssm/hybrid archs.
 """
 from . import flash_attention, fused_update, ssd_scan
-from .compat import tpu_compiler_params
 
-__all__ = ["flash_attention", "fused_update", "ssd_scan",
-           "tpu_compiler_params"]
+__all__ = ["flash_attention", "fused_update", "ssd_scan"]

@@ -28,8 +28,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..compat import tpu_compiler_params
-
 DEFAULT_BLOCK_Q = 512
 DEFAULT_BLOCK_K = 512
 NEG_INF = -1e30
@@ -117,7 +115,7 @@ def flash_attention_kernel(q, k, v, *, causal: bool = True,
             pltpu.VMEM((block_q, 1), jnp.float32),     # running denom l
             pltpu.VMEM((block_q, d), jnp.float32),     # output accumulator
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

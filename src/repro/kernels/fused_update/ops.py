@@ -47,8 +47,10 @@ def resolve_kernel_mode(mode: str) -> str:
 
 
 def kernel_interpret() -> bool:
-    """Whether a forced-Pallas run must use interpret mode (no TPU)."""
-    return jax.default_backend() != "tpu"
+    """Whether a forced-Pallas run uses interpret mode: on the CPU
+    backend only. Any accelerator compiles the kernel (and a non-TPU one
+    refuses it loudly rather than running the interpreter)."""
+    return jax.default_backend() == "cpu"
 
 
 def clamp_block_rows(n: int, block_rows: int = DEFAULT_BLOCK_ROWS) -> int:

@@ -29,8 +29,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..compat import tpu_compiler_params
-
 
 def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref,
             y_ref, state_ref, expcum_ref, decay_ref, *, chunk: int):
@@ -97,7 +95,7 @@ def ssd_intra_chunk(X, dtv, A, Bh, Ch, *, chunk: int, interpret: bool = False):
             jax.ShapeDtypeStruct((BH, S), jnp.float32),
             jax.ShapeDtypeStruct((BH, nc), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
         name="ssd_intra_chunk",

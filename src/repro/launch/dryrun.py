@@ -176,9 +176,8 @@ def _calibrate(cfg, shape, mesh, *, microbatches, fsdp):
         vshape = jax.tree.map(
             lambda p: jax.ShapeDtypeStruct(p.shape, jnp.float32), pshape)
         from jax.sharding import NamedSharding, PartitionSpec as P
-        from repro.launch.mesh import set_mesh
         repl = NamedSharding(mesh, P())
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             c = jax.jit(upd, in_shardings=(pshard, pshard, pshard, repl)) \
                 .lower(pshape, vshape, vshape,
                        jax.ShapeDtypeStruct((), jnp.int32)).compile()

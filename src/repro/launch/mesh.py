@@ -8,115 +8,36 @@ async parameter server applies (launch/train.py).
 
 Functions, not module constants: importing this module never touches jax
 device state (the dry-run must set XLA_FLAGS before first jax init).
-
-Compat: ``jax.sharding.AxisType`` (and the ``axis_types=`` kwarg of
-``jax.make_mesh``) only exist on newer JAX releases; on older versions we
-fall back to a plain ``jax.make_mesh`` — every mesh axis defaults to the
-same (auto) partitioning behaviour there. ``AbstractMesh`` likewise changed
-its constructor signature between releases; ``make_abstract_mesh`` accepts
-(shape, axes) and adapts. ``set_mesh`` / ``get_abstract_mesh`` below shim
-the newer ``jax.set_mesh`` context and ``jax.sharding.get_abstract_mesh``
-lookup onto the pinned jax 0.4.37, where neither exists — model code must
-import them from here, never from jax directly.
+``get_abstract_mesh`` is the one place model code reads the ambient mesh
+(set by ``jax.set_mesh``) from.
 """
 from __future__ import annotations
 
-import contextlib
-import threading
-
 import jax
-
-try:  # JAX >= 0.5-ish exposes explicit axis types
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - exercised on older JAX only
-    AxisType = None
-
-# Mesh contexts our set_mesh shim has entered (old-JAX path only); the
-# newer-JAX path delegates the bookkeeping to jax.set_mesh itself.
-# Thread-local, like the jax resource env it emulates — concurrent
-# dry-run calibrations must not see each other's meshes.
-_LOCAL = threading.local()
-
-
-def _mesh_stack() -> list:
-    stack = getattr(_LOCAL, "stack", None)
-    if stack is None:
-        stack = _LOCAL.stack = []
-    return stack
-
-
-@contextlib.contextmanager
-def set_mesh(mesh):
-    """Compat twin of ``jax.set_mesh(mesh)`` (a context manager there).
-
-    Newer JAX: delegate. Older JAX (the pinned 0.4.37): enter the mesh's
-    resource-env context — pjit/GSPMD resolve bare PartitionSpec axis names
-    against it exactly as the newer API does — and record it so
-    ``get_abstract_mesh`` can answer inside the block."""
-    if hasattr(jax, "set_mesh"):
-        with jax.set_mesh(mesh):
-            yield mesh
-        return
-    stack = _mesh_stack()
-    stack.append(mesh)
-    try:
-        with mesh:
-            yield mesh
-    finally:
-        stack.pop()
+from jax.sharding import AxisType
 
 
 def get_abstract_mesh():
-    """Compat twin of ``jax.sharding.get_abstract_mesh()``.
-
-    Returns the mesh of the innermost active ``set_mesh`` context, or None
-    when there is none — callers treat None as "no sharding constraint"
-    (host tests run meshless). On old JAX the returned object is the
-    concrete Mesh, which exposes the same ``.axis_names`` / ``.shape``
-    mapping the callers consult; a mesh entered via a plain ``with mesh:``
-    block is also honored through jax's thread resource env."""
-    fn = getattr(jax.sharding, "get_abstract_mesh", None)
-    if fn is not None:
-        m = fn()
-        if m is None or not getattr(m, "axis_names", ()):
-            return None     # empty sentinel mesh -> meshless semantics
-        return m
-    stack = _mesh_stack()
-    if stack:
-        return stack[-1]
-    try:  # plain `with mesh:` contexts (old-JAX resource env)
-        env_mesh = jax._src.mesh.thread_resources.env.physical_mesh
-        if env_mesh is not None and not env_mesh.empty:
-            return env_mesh
-    except AttributeError:  # pragma: no cover - layout drift across versions
-        pass
-    return None
+    """The mesh of the innermost active ``jax.set_mesh`` context, or None when
+    there is none — callers treat None as "no sharding constraint" (host
+    tests run meshless)."""
+    m = jax.sharding.get_abstract_mesh()
+    if m is None or not m.axis_names:
+        return None     # empty sentinel mesh -> meshless semantics
+    return m
 
 
 def make_mesh(shape, axes):
     """Arbitrary mesh with Auto axis types (tests / small-scale drivers)."""
     shape, axes = tuple(shape), tuple(axes)
-    if AxisType is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
     return make_mesh(shape, axes)
-
-
-def make_abstract_mesh(shape, axes):
-    """Device-less mesh for lowering-only tests, across AbstractMesh APIs."""
-    from jax.sharding import AbstractMesh
-
-    shape, axes = tuple(shape), tuple(axes)
-    try:
-        return AbstractMesh(shape, axes)
-    except TypeError:  # older signature: tuple of (name, size) pairs
-        return AbstractMesh(tuple(zip(axes, shape)))
 
 
 def make_host_mesh(model: int = 1):

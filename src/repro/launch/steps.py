@@ -209,8 +209,7 @@ def lower_cell(cfg, shape: str, mesh, *, eta: float = 1e-2, beta: float = 0.9,
     # place for serving — without it XLA allocates a second copy of the
     # largest state (31 GiB/dev observed for the 76B decode cell).
     donate = {"train": (0, 1), "prefill": (2,), "decode": (1,)}[kind]
-    from .mesh import set_mesh
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         jitted = jax.jit(step, in_shardings=in_sh, out_shardings=out_sh,
                          donate_argnums=donate)
         lowered = jitted.lower(*args)

@@ -276,6 +276,7 @@ def run(cfg_model, icfg: IslandConfig, *, log=print):
 
 def main():
     from repro.configs import get_config, get_smoke_config
+    from repro.launch.cache import enable_compile_cache
 
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-0.6b")
@@ -294,6 +295,7 @@ def main():
                          "reference; auto = Pallas on TPU)")
     ap.add_argument("--ckpt-dir", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     icfg = IslandConfig(n_islands=args.islands, slots=args.slots,

@@ -102,10 +102,10 @@ ENTRY %main (a: f32[8192,688]) -> f32[8192,688] {
 
 def test_real_compile_collectives_parse():
     """End-to-end: a psum under a 1-device mesh parses without error."""
-    from repro.launch.mesh import make_mesh, set_mesh
+    from repro.launch.mesh import make_mesh
     mesh = make_mesh((1, 1), ("data", "model"))
     from jax.sharding import NamedSharding, PartitionSpec as P
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         f = jax.jit(lambda x: x @ x.T,
                     in_shardings=NamedSharding(mesh, P("data", "model")))
         c = f.lower(jax.ShapeDtypeStruct((8, 8), jnp.float32)).compile()

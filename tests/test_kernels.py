@@ -190,7 +190,7 @@ class TestBlockRowsClamp:
         auto = resolve_kernel_mode("auto")
         on_tpu = jax.default_backend() == "tpu"
         assert auto == ("pallas" if on_tpu else "reference")
-        assert kernel_interpret() == (not on_tpu)
+        assert kernel_interpret() == (jax.default_backend() == "cpu")
         with pytest.raises(ValueError, match="unknown kernel mode"):
             resolve_kernel_mode("bogus")
 

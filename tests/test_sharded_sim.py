@@ -124,6 +124,19 @@ class TestShardedParity:
         assert np.array_equal(r0.trace_Q, r1.trace_Q)
         assert np.array_equal(r0.trace_H, r1.trace_H)
 
+    def test_reports_placement(self):
+        """The result names the devices the scan's carry and arrival
+        operands sat on, the user axis split evenly between them."""
+        d = _n_devices()
+        _, r0 = _run(0, 23)
+        _, r1 = _run(d, 23)
+        for r, n_dev in ((r0, 1), (r1, d)):
+            assert set(r.placement) == {"state.mode", "state.energy",
+                                        "app_sched"}
+            for ids, shard_shape in r.placement.values():
+                assert len(ids) == n_dev
+                assert shard_shape[-1] == -(-23 // n_dev)
+
     def test_single_device_mesh_degenerates(self):
         """n_devices=1 runs the plain path (no constraint ops) and still
         matches."""

@@ -2,7 +2,7 @@
 import jax
 import jax.numpy as jnp
 import pytest
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AbstractMesh, PartitionSpec as P
 
 from repro.configs import ARCHS, get_config
 from repro.launch.mesh import make_mesh
@@ -14,8 +14,7 @@ def _mesh16():
     # divisibility, so build an ABSTRACT mesh via jax.sharding.Mesh over a
     # reshaped device array is impossible on CPU with 1 device. Instead use
     # AbstractMesh (no devices needed).
-    from repro.launch.mesh import make_abstract_mesh
-    return make_abstract_mesh((16, 16), ("data", "model"))
+    return AbstractMesh((16, 16), ("data", "model"))
 
 
 @pytest.mark.parametrize("arch", ARCHS)
@@ -114,7 +113,6 @@ def test_cache_specs_prefer_kv_head_sharding_else_seq():
 
 
 def test_multi_pod_dp_axes():
-    from repro.launch.mesh import make_abstract_mesh
-    mesh = make_abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+    mesh = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
     assert sharding.dp_axes(mesh) == ("pod", "data")
     assert sharding._prod_dp(mesh) == 32

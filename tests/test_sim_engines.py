@@ -169,6 +169,19 @@ class TestJaxBackend:
         assert a.mean_H > 0
         assert_equivalent(a, b)
 
+    def test_staleness_pressure_order_free_branch(self):
+        """With H > 0 the online hook decides order-free users without the
+        per-user replay and sums Eq. 16's gaps as a tree, not the replay's
+        left fold; decisions stay the oracle's, H within rounding."""
+        # a few slots hold users whose decision depends on their in-slot
+        # lag, so all three branches of the hook run
+        kw = dict(L_b=10.0, V=2000.0, app_arrival_p=0.002, horizon_s=2000,
+                  n_users=64)
+        a = run("online", "loop", **kw)
+        b = run("online", "jax", **kw)
+        assert a.mean_H > 0
+        assert_equivalent(a, b)
+
     def test_offline_runs_on_jax(self):
         """The offline knapsack plans through a host callback at window
         slots: engine='jax' resolves to jax (it used to degrade to the
