@@ -36,6 +36,7 @@ import dataclasses
 from typing import Any, Callable, Dict, List, Optional, Union
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..kernels.fused_update import KERNEL_MODES
 from .aggregation import (AggregationRule, aggregation_support,
@@ -594,26 +595,31 @@ class FederatedSim:
         return engine
 
     def run(self) -> SimResult:
-        if getattr(self, "_ran", False):
-            # a run consumes the mutable EngineState / UserState objects;
-            # reallocate them so repeated run() calls (warmup-then-timed
-            # patterns) start fresh instead of continuing silently from
-            # the previous run's state. Real-ML backends/hook closures are
-            # single-run by contract and are NOT reset here.
-            self.state = EngineState.init(self.cfg.n_users, self.cfg,
-                                          self.policy, agg=self.agg,
-                                          fleet=self.fleet_spec,
-                                          dynamics=self.dynamics)
-            self.users = [UserState(device=d)
-                          for d in self.fleet_spec.devices]
-            self.sched.Q = 0.0
-            self.sched.H = 0.0
-        self._ran = True
         engine = self.resolve_engine()
-        if engine == "loop":
-            return self._run_loop()
-        from .vector_engine import run_vectorized
-        return run_vectorized(self, backend=engine)
+        with TraceAnnotation("sim.run", engine=engine,
+                             n_users=self.cfg.n_users,
+                             slots=n_slots(self.cfg)):
+            if getattr(self, "_ran", False):
+                # a run consumes the mutable EngineState / UserState
+                # objects; reallocate them so repeated run() calls
+                # (warmup-then-timed patterns) start fresh instead of
+                # continuing silently from the previous run's state.
+                # Real-ML backends/hook closures are single-run by
+                # contract and are NOT reset here.
+                with TraceAnnotation("sim.reset"):
+                    self.state = EngineState.init(
+                        self.cfg.n_users, self.cfg, self.policy,
+                        agg=self.agg, fleet=self.fleet_spec,
+                        dynamics=self.dynamics)
+                    self.users = [UserState(device=d)
+                                  for d in self.fleet_spec.devices]
+                    self.sched.Q = 0.0
+                    self.sched.H = 0.0
+            self._ran = True
+            if engine == "loop":
+                return self._run_loop()
+            from .vector_engine import run_vectorized
+            return run_vectorized(self, backend=engine)
 
     def _run_loop(self) -> SimResult:
         cfg = self.cfg

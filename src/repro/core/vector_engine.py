@@ -86,6 +86,7 @@ from types import SimpleNamespace
 from typing import List, Tuple
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from .engine_state import (EngineState, PushBuffer, PushLog, MODE_COOL,
                            MODE_OFF, MODE_TRAIN, MODE_WAIT, PLAN_CORUN,
@@ -615,63 +616,66 @@ def _build_jax_chunk_fn(n: int, chunk: int, T: int, policy, overhead: bool,
             # dynamics rng draw precedes the policy's so the key chain
             # matches the host engines bit for bit
             if dyn_active:
-                # dv.n is the LIVE user count — hooks draw at it and pad
-                # via dv.pad_users so the threefry stream matches the
-                # host engines at any padding (dv.n_arr == n unsharded)
-                dv = SimpleNamespace(jnp=jnp, jax=jax, lax=lax, n=n,
-                                     n_arr=n_arr, pad_users=pad_users,
-                                     repl=repl_pin,
-                                     float_dtype=f, int_dtype=i,
-                                     rng_key=rng_key, mode=mode,
-                                     corun=corun, t_d=t_d, fp_zero=fp_zero,
-                                     consts=dyn_ops)
-                dyn, eff = dynamics.scan_step(dyn, dv)
-                rng_key = dv.rng_key
-                up = eff.up
-                wd, wu = eff.went_down, eff.went_up
-                net_extra = eff.net_extra
-                dwait = wd & (mode == MODE_WAIT)
-                dtrain = wd & (mode == MODE_TRAIN)
-                dcool = wd & (mode == MODE_COOL)
-                departures = jnp.sum(dwait)
-                if dyn_lose:
-                    mode = jnp.where(dwait | dtrain | dcool, MODE_OFF,
-                                     mode)
-                    train_rem = jnp.where(dtrain, 0.0, train_rem)
-                    in_flight = in_flight - jnp.sum(dtrain)
-                else:       # resume: paused, pays the extra seconds
-                    mode = jnp.where(dwait | dcool, MODE_OFF, mode)
-                    train_rem = jnp.where(dtrain,
-                                          train_rem + eff.resume_penalty,
-                                          train_rem)
-                ret = wu & (mode == MODE_OFF)
-                mode = jnp.where(ret, MODE_COOL, mode)
-                cooldown = jnp.where(ret, ready_delay + net_extra,
-                                     cooldown)
+                with jax.named_scope("slot.dynamics"):
+                    # dv.n is the LIVE user count — hooks draw at it and pad
+                    # via dv.pad_users so the threefry stream matches the
+                    # host engines at any padding (dv.n_arr == n unsharded)
+                    dv = SimpleNamespace(jnp=jnp, jax=jax, lax=lax, n=n,
+                                         n_arr=n_arr, pad_users=pad_users,
+                                         repl=repl_pin,
+                                         float_dtype=f, int_dtype=i,
+                                         rng_key=rng_key, mode=mode,
+                                         corun=corun, t_d=t_d, fp_zero=fp_zero,
+                                         consts=dyn_ops)
+                    dyn, eff = dynamics.scan_step(dyn, dv)
+                    rng_key = dv.rng_key
+                    up = eff.up
+                    wd, wu = eff.went_down, eff.went_up
+                    net_extra = eff.net_extra
+                    dwait = wd & (mode == MODE_WAIT)
+                    dtrain = wd & (mode == MODE_TRAIN)
+                    dcool = wd & (mode == MODE_COOL)
+                    departures = jnp.sum(dwait)
+                    if dyn_lose:
+                        mode = jnp.where(dwait | dtrain | dcool, MODE_OFF,
+                                         mode)
+                        train_rem = jnp.where(dtrain, 0.0, train_rem)
+                        in_flight = in_flight - jnp.sum(dtrain)
+                    else:       # resume: paused, pays the extra seconds
+                        mode = jnp.where(dwait | dcool, MODE_OFF, mode)
+                        train_rem = jnp.where(dtrain,
+                                              train_rem + eff.resume_penalty,
+                                              train_rem)
+                    ret = wu & (mode == MODE_OFF)
+                    mode = jnp.where(ret, MODE_COOL, mode)
+                    cooldown = jnp.where(ret, ready_delay + net_extra,
+                                         cooldown)
 
             # apps
-            has_app0 = app >= 0
-            new_app = srow & ~has_app0
-            app_rem = jnp.where(has_app0, app_rem - t_d, app_rem)
-            ended = has_app0 & (app_rem <= 0.0)
-            app = jnp.where(ended, -1, app)
-            app_rem = jnp.where(ended, 0.0, app_rem)
-            app = jnp.where(new_app, crow, app)
-            aid = jnp.maximum(app, 0)
-            tcor_g = T_COR[ar, aid]
-            papp_g = P_APP[ar, aid]
-            pcor_g = P_COR[ar, aid]
-            app_rem = jnp.where(new_app, tcor_g, app_rem)
+            with jax.named_scope("slot.apps"):
+                has_app0 = app >= 0
+                new_app = srow & ~has_app0
+                app_rem = jnp.where(has_app0, app_rem - t_d, app_rem)
+                ended = has_app0 & (app_rem <= 0.0)
+                app = jnp.where(ended, -1, app)
+                app_rem = jnp.where(ended, 0.0, app_rem)
+                app = jnp.where(new_app, crow, app)
+                aid = jnp.maximum(app, 0)
+                tcor_g = T_COR[ar, aid]
+                papp_g = P_APP[ar, aid]
+                pcor_g = P_COR[ar, aid]
+                app_rem = jnp.where(new_app, tcor_g, app_rem)
 
             # cooldown -> waiting
-            cooling = mode == MODE_COOL
-            cooldown = jnp.where(cooling, cooldown - 1, cooldown)
-            to_wait = cooling & (cooldown <= 0)
-            mode = jnp.where(to_wait, MODE_WAIT, mode)
-            plan = jnp.where(to_wait, PLAN_HOLD, s.plan)
-            arrivals = jnp.sum(to_wait)
-            waiting = mode == MODE_WAIT
-            has_app = app >= 0
+            with jax.named_scope("slot.cooldown"):
+                cooling = mode == MODE_COOL
+                cooldown = jnp.where(cooling, cooldown - 1, cooldown)
+                to_wait = cooling & (cooldown <= 0)
+                mode = jnp.where(to_wait, MODE_WAIT, mode)
+                plan = jnp.where(to_wait, PLAN_HOLD, s.plan)
+                arrivals = jnp.sum(to_wait)
+                waiting = mode == MODE_WAIT
+                has_app = app >= 0
 
             # decisions: the policy's carry hook, on a mutable slot view.
             # Under a mesh every hook input (and the carry) is constrained
@@ -682,80 +686,82 @@ def _build_jax_chunk_fn(n: int, chunk: int, T: int, policy, overhead: bool,
             # (a shard-local partial sum + AllReduce would reassociate
             # them). The engine keeps its own sharded views of the same
             # arrays for the surrounding per-user phases.
-            if mesh is None:
-                pol_carry = s.carry
-                sv_waiting, sv_has_app, sv_app = waiting, has_app, app
-                sv_updates, sv_plan, sv_idle = updates, plan, idle_gap
-                sv_pcor, sv_papp, sv_tcor = pcor_g, papp_g, tcor_g
-                sv_PT, sv_TT, sv_PI, sv_PS = PT, TT, PI, PS
-            else:
-                pol_carry = jax.tree.map(repl, s.carry)
-                sv_waiting, sv_has_app, sv_app = \
-                    repl(waiting), repl(has_app), repl(app)
-                sv_updates, sv_plan, sv_idle = \
-                    repl(updates), repl(plan), repl(idle_gap)
-                sv_pcor, sv_papp, sv_tcor = \
-                    repl(pcor_g), repl(papp_g), repl(tcor_g)
-                sv_PT, sv_TT, sv_PI, sv_PS = \
-                    repl(PT), repl(TT), repl(PI), repl(PS)
-            sv = SimpleNamespace(
-                jnp=jnp, lax=lax, jax=jax, n=n, T=T,
-                n_arr=n_arr, pad_users=pad_users, repl=repl_pin,
-                float_dtype=f, int_dtype=i, t=t,
-                waiting=sv_waiting, has_app=sv_has_app, app=sv_app,
-                updates=sv_updates,
-                pcor_g=sv_pcor, papp_g=sv_papp, tcor_g=sv_tcor,
-                PT=sv_PT, TT=sv_TT, PI=sv_PI, PS=sv_PS,
-                T_COR=T_COR, SRATE=SRATE,
-                app_sched=app_sched, app_choice=app_choice,
-                plan=sv_plan, idle_gap=sv_idle, in_flight=in_flight,
-                version=version, round_open=s.round_open, Q=Q, H=H,
-                rng_key=rng_key,
-                V=V, L_b=L_b, epsilon=epsilon, eta=eta, beta=beta,
-                v_norm0=v_norm0, t_d=t_d, fp_zero=fp_zero,
-                offline_window=offline_window,
-                offline_resolution=offline_resolution,
-                consts=pol_ops, statics=statics)
-            carry, (start, gap_sum) = policy.scan_step(pol_carry, sv)
-            idle_gap = sv.idle_gap
-            round_open = sv.round_open
-            plan = sv.plan
-            rng_key = sv.rng_key
-            if mesh is not None:
-                # hook outputs return to the sharded layout for the
-                # per-user phases below. The inner repl() pin is load-
-                # bearing: without it GSPMD back-propagates the sharded
-                # consumer layout INTO the hook graph, reassociating its
-                # float reductions (Eq. 16's gap_sum) and partitioning
-                # its lax.scan bodies — the hook must compute fully
-                # replicated to stay bit-identical to the unsharded scan
-                start = shard(repl(start))
-                idle_gap = shard(repl(idle_gap))
-                plan = shard(repl(plan))
-                carry = jax.tree.map(lambda x: place(repl(x)), carry)
-            served = jnp.sum(start)
+            with jax.named_scope("slot.policy"):
+                if mesh is None:
+                    pol_carry = s.carry
+                    sv_waiting, sv_has_app, sv_app = waiting, has_app, app
+                    sv_updates, sv_plan, sv_idle = updates, plan, idle_gap
+                    sv_pcor, sv_papp, sv_tcor = pcor_g, papp_g, tcor_g
+                    sv_PT, sv_TT, sv_PI, sv_PS = PT, TT, PI, PS
+                else:
+                    pol_carry = jax.tree.map(repl, s.carry)
+                    sv_waiting, sv_has_app, sv_app = \
+                        repl(waiting), repl(has_app), repl(app)
+                    sv_updates, sv_plan, sv_idle = \
+                        repl(updates), repl(plan), repl(idle_gap)
+                    sv_pcor, sv_papp, sv_tcor = \
+                        repl(pcor_g), repl(papp_g), repl(tcor_g)
+                    sv_PT, sv_TT, sv_PI, sv_PS = \
+                        repl(PT), repl(TT), repl(PI), repl(PS)
+                sv = SimpleNamespace(
+                    jnp=jnp, lax=lax, jax=jax, n=n, T=T,
+                    n_arr=n_arr, pad_users=pad_users, repl=repl_pin,
+                    float_dtype=f, int_dtype=i, t=t,
+                    waiting=sv_waiting, has_app=sv_has_app, app=sv_app,
+                    updates=sv_updates,
+                    pcor_g=sv_pcor, papp_g=sv_papp, tcor_g=sv_tcor,
+                    PT=sv_PT, TT=sv_TT, PI=sv_PI, PS=sv_PS,
+                    T_COR=T_COR, SRATE=SRATE,
+                    app_sched=app_sched, app_choice=app_choice,
+                    plan=sv_plan, idle_gap=sv_idle, in_flight=in_flight,
+                    version=version, round_open=s.round_open, Q=Q, H=H,
+                    rng_key=rng_key,
+                    V=V, L_b=L_b, epsilon=epsilon, eta=eta, beta=beta,
+                    v_norm0=v_norm0, t_d=t_d, fp_zero=fp_zero,
+                    offline_window=offline_window,
+                    offline_resolution=offline_resolution,
+                    consts=pol_ops, statics=statics)
+                carry, (start, gap_sum) = policy.scan_step(pol_carry, sv)
+                idle_gap = sv.idle_gap
+                round_open = sv.round_open
+                plan = sv.plan
+                rng_key = sv.rng_key
+                if mesh is not None:
+                    # hook outputs return to the sharded layout for the
+                    # per-user phases below. The inner repl() pin is load-
+                    # bearing: without it GSPMD back-propagates the sharded
+                    # consumer layout INTO the hook graph, reassociating its
+                    # float reductions (Eq. 16's gap_sum) and partitioning
+                    # its lax.scan bodies — the hook must compute fully
+                    # replicated to stay bit-identical to the unsharded scan
+                    start = shard(repl(start))
+                    idle_gap = shard(repl(idle_gap))
+                    plan = shard(repl(plan))
+                    carry = jax.tree.map(lambda x: place(repl(x)), carry)
+                served = jnp.sum(start)
 
             # begin training
-            mode = jnp.where(start, MODE_TRAIN, mode)
-            corun = jnp.where(start, has_app, corun)
-            train_rem = jnp.where(start, jnp.where(has_app, tcor_g, TT),
-                                  train_rem)
-            pulled_at = jnp.where(start, version, pulled_at)
-            in_flight = in_flight + served
+            with jax.named_scope("slot.train"):
+                mode = jnp.where(start, MODE_TRAIN, mode)
+                corun = jnp.where(start, has_app, corun)
+                train_rem = jnp.where(start, jnp.where(has_app, tcor_g, TT),
+                                      train_rem)
+                pulled_at = jnp.where(start, version, pulled_at)
+                in_flight = in_flight + served
 
-            # training progression (a down "resume" trainer is paused)
-            training = (mode == MODE_TRAIN) & up if dyn_active \
-                else mode == MODE_TRAIN
-            train_rem = jnp.where(training, train_rem - t_d, train_rem)
-            fin = training & (train_rem <= 0.0)
-            kfin = jnp.sum(fin)
-            updates = updates + fin
-            mode = jnp.where(fin, MODE_COOL, mode)
-            cooldown = jnp.where(fin, ready_delay + net_extra if dyn_active
-                                 else ready_delay, cooldown)
-            idle_gap = jnp.where(fin, 0.0, idle_gap)
-            in_flight = in_flight - kfin
-            corun_updates = s.corun_updates + jnp.sum(fin & corun)
+                # training progression (a down "resume" trainer is paused)
+                training = (mode == MODE_TRAIN) & up if dyn_active \
+                    else mode == MODE_TRAIN
+                train_rem = jnp.where(training, train_rem - t_d, train_rem)
+                fin = training & (train_rem <= 0.0)
+                kfin = jnp.sum(fin)
+                updates = updates + fin
+                mode = jnp.where(fin, MODE_COOL, mode)
+                cooldown = jnp.where(fin, ready_delay + net_extra if dyn_active
+                                     else ready_delay, cooldown)
+                idle_gap = jnp.where(fin, 0.0, idle_gap)
+                in_flight = in_flight - kfin
+                corun_updates = s.corun_updates + jnp.sum(fin & corun)
 
             # push events: scatter one fixed-width row per finisher at the
             # buffer cursor (user-index order within the slot, the loop
@@ -764,89 +770,93 @@ def _build_jax_chunk_fn(n: int, chunk: int, T: int, policy, overhead: bool,
             events = s.events
             agg_carry = s.agg_carry
             if collect:
-                # the scatter runs REPLICATED under a mesh (pads never
-                # finish, so the cumsum ranks and the buffer cursor match
-                # the unsharded scan; the buffer itself is a replicated
-                # carry leaf) — cheap, since only (n,) vectors and the
-                # O(capacity) buffer are involved, never the big state
-                if mesh is None:
-                    fin_e, corun_e, pulled_e, ar_e = fin, corun, \
-                        pulled_at, ar
-                else:
-                    fin_e, corun_e, pulled_e, ar_e = \
-                        repl(fin), repl(corun), repl(pulled_at), repl(ar)
-                rank = jnp.cumsum(fin_e) - fin_e
-                if policy.sync_rounds:
-                    lag = version - pulled_e
-                    vn = _jax_trace_v_norm(v_norm0, version, jnp, fp_zero)
-                else:
-                    vers = version + rank
-                    lag = vers - pulled_e
-                    vn = _jax_trace_v_norm(v_norm0, vers, jnp, fp_zero)
-                gap = _jax_gradient_gap(vn, lag, eta, beta)
-                if policy.sync_rounds:
-                    # FedAvg rounds average; no per-push weight
-                    w = jnp.ones((n_arr,), f)
-                else:
-                    pv = SimpleNamespace(
-                        jnp=jnp, lax=lax, jax=jax, float_dtype=f,
-                        lag=lag, gap=gap, v_norm=vn, users=ar_e,
-                        consts=agg_ops)
-                    if mesh is not None:
-                        agg_carry = jax.tree.map(repl, agg_carry)
-                    agg_carry, w = agg.scan_weight(agg_carry, pv)
-                    if mesh is not None:
-                        agg_carry = jax.tree.map(place, agg_carry)
-                    w = jnp.broadcast_to(w, (n_arr,))
-                rows = jnp.stack(
-                    [jnp.broadcast_to(t, (n_arr,)).astype(f),
-                     ar_e.astype(f),
-                     lag.astype(f), gap.astype(f), corun_e.astype(f),
-                     w.astype(f)],
-                    axis=1)
-                pos = jnp.where(fin_e, events.count + rank, capacity)
-                events = PushBuffer(
-                    events.rows.at[pos].set(rows, mode="drop"),
-                    events.count + kfin)
+                with jax.named_scope("slot.push_log"):
+                    # the scatter runs REPLICATED under a mesh (pads never
+                    # finish, so the cumsum ranks and the buffer cursor match
+                    # the unsharded scan; the buffer itself is a replicated
+                    # carry leaf) — cheap, since only (n,) vectors and the
+                    # O(capacity) buffer are involved, never the big state
+                    if mesh is None:
+                        fin_e, corun_e, pulled_e, ar_e = fin, corun, \
+                            pulled_at, ar
+                    else:
+                        fin_e, corun_e, pulled_e, ar_e = \
+                            repl(fin), repl(corun), repl(pulled_at), repl(ar)
+                    rank = jnp.cumsum(fin_e) - fin_e
+                    if policy.sync_rounds:
+                        lag = version - pulled_e
+                        vn = _jax_trace_v_norm(v_norm0, version, jnp, fp_zero)
+                    else:
+                        vers = version + rank
+                        lag = vers - pulled_e
+                        vn = _jax_trace_v_norm(v_norm0, vers, jnp, fp_zero)
+                    gap = _jax_gradient_gap(vn, lag, eta, beta)
+                    if policy.sync_rounds:
+                        # FedAvg rounds average; no per-push weight
+                        w = jnp.ones((n_arr,), f)
+                    else:
+                        pv = SimpleNamespace(
+                            jnp=jnp, lax=lax, jax=jax, float_dtype=f,
+                            lag=lag, gap=gap, v_norm=vn, users=ar_e,
+                            consts=agg_ops)
+                        if mesh is not None:
+                            agg_carry = jax.tree.map(repl, agg_carry)
+                        agg_carry, w = agg.scan_weight(agg_carry, pv)
+                        if mesh is not None:
+                            agg_carry = jax.tree.map(place, agg_carry)
+                        w = jnp.broadcast_to(w, (n_arr,))
+                    rows = jnp.stack(
+                        [jnp.broadcast_to(t, (n_arr,)).astype(f),
+                         ar_e.astype(f),
+                         lag.astype(f), gap.astype(f), corun_e.astype(f),
+                         w.astype(f)],
+                        axis=1)
+                    pos = jnp.where(fin_e, events.count + rank, capacity)
+                    events = PushBuffer(
+                        events.rows.at[pos].set(rows, mode="drop"),
+                        events.count + kfin)
 
-            if policy.sync_rounds:
-                closed = round_open & (jnp.sum(mode == MODE_TRAIN) == 0)
-                version = version + closed
-                round_open = round_open & ~closed
-            else:
-                version = version + kfin
+            with jax.named_scope("slot.train"):
+                if policy.sync_rounds:
+                    closed = round_open & (jnp.sum(mode == MODE_TRAIN) == 0)
+                    version = version + closed
+                    round_open = round_open & ~closed
+                else:
+                    version = version + kfin
 
             # energy (Eq. 10)
-            training = mode == MODE_TRAIN
-            p = jnp.where(training,
-                          jnp.where(has_app, pcor_g, PT),
-                          jnp.where(has_app, papp_g, PI))
-            if overhead and policy.uses_online_queue:
-                p = jnp.where(mode == MODE_WAIT, p + (PS - PI), p)
-            if dyn_active:     # a down device draws nothing
-                p = jnp.where(up, p, 0.0)
-            # + fp_zero: round p*t_d before accumulating, as the host does
-            # (fma contraction would skip it — see _jax_trace_v_norm)
-            energy = energy + (p * t_d + fp_zero)
+            with jax.named_scope("slot.energy"):
+                training = mode == MODE_TRAIN
+                p = jnp.where(training,
+                              jnp.where(has_app, pcor_g, PT),
+                              jnp.where(has_app, papp_g, PI))
+                if overhead and policy.uses_online_queue:
+                    p = jnp.where(mode == MODE_WAIT, p + (PS - PI), p)
+                if dyn_active:     # a down device draws nothing
+                    p = jnp.where(up, p, 0.0)
+                # + fp_zero: round p*t_d before accumulating, as the host does
+                # (fma contraction would skip it — see _jax_trace_v_norm)
+                energy = energy + (p * t_d + fp_zero)
 
             # queues (Eqs. 15-16; departures extend Eq. 15 under churn)
-            if dyn_active:
-                Q = jnp.maximum(Q - served - departures, 0.0) + arrivals
-            else:
-                Q = jnp.maximum(Q - served, 0.0) + arrivals
-            H = jnp.maximum(H + gap_sum - L_b, 0.0)
-            s2 = EngineState(
-                mode=mode, cooldown=cooldown, app=app, app_rem=app_rem,
-                train_rem=train_rem, corun=corun, idle_gap=idle_gap,
-                pulled_at=pulled_at, energy=energy, updates=updates,
-                plan=plan, version=version, in_flight=in_flight,
-                round_open=round_open, Q=Q, H=H,
-                sum_Q=s.sum_Q + Q, sum_H=s.sum_H + H,
-                corun_updates=corun_updates, rng_key=rng_key,
-                carry=carry, agg_carry=agg_carry, dyn=dyn, events=events)
-            if mesh is not None:
-                s2 = constrain_state(s2)
-            return s2, (Q, H, jnp.sum(energy))
+            with jax.named_scope("slot.queues"):
+                if dyn_active:
+                    Q = jnp.maximum(Q - served - departures, 0.0) + arrivals
+                else:
+                    Q = jnp.maximum(Q - served, 0.0) + arrivals
+                H = jnp.maximum(H + gap_sum - L_b, 0.0)
+                s2 = EngineState(
+                    mode=mode, cooldown=cooldown, app=app, app_rem=app_rem,
+                    train_rem=train_rem, corun=corun, idle_gap=idle_gap,
+                    pulled_at=pulled_at, energy=energy, updates=updates,
+                    plan=plan, version=version, in_flight=in_flight,
+                    round_open=round_open, Q=Q, H=H,
+                    sum_Q=s.sum_Q + Q, sum_H=s.sum_H + H,
+                    corun_updates=corun_updates, rng_key=rng_key,
+                    carry=carry, agg_carry=agg_carry, dyn=dyn, events=events)
+                if mesh is not None:
+                    s2 = constrain_state(s2)
+                return s2, (Q, H, jnp.sum(energy))
 
         return lax.scan(step, state, (sched_c, choice_c, ts))
 
@@ -1125,17 +1135,14 @@ def _run_jax(sim) -> SimResult:
         mesh = make_sim_mesh(cfg.n_devices)
         if mesh.devices.size == 1:
             mesh = None
-    rs = _jax_run_setup(sim, jax, jnp,
-                        n_devices=mesh.devices.size if mesh else 1)
-    if mesh is not None:
-        n_arr = pad_to_devices(rs.n, mesh.devices.size)
-        rs = _mesh_ops_to_device(_pad_setup(rs, n_arr, sim), mesh, n_arr,
-                                 jax, jnp)
-    else:
-        rs = _ops_to_device(rs, jax, jnp)
+    with TraceAnnotation("scan.setup"):
+        rs = _jax_run_setup(sim, jax, jnp,
+                            n_devices=mesh.devices.size if mesh else 1)
+        if mesh is not None:
+            n_arr = pad_to_devices(rs.n, mesh.devices.size)
+            rs = _pad_setup(rs, n_arr, sim)
     n, T, chunk, collect, f, i = rs.n, rs.T, rs.chunk, rs.collect, rs.f, rs.i
     cap = rs.cap
-    state = rs.state
 
     def fresh_events(c):
         ev = PushBuffer(jnp.zeros((c, 6), f), jnp.asarray(0, i))
@@ -1144,81 +1151,98 @@ def _run_jax(sim) -> SimResult:
                             jax.device_put(ev.count, rs.repl_sharding))
         return ev
 
-    if collect:
-        state = state.replace(events=fresh_events(cap))
+    with TraceAnnotation("scan.to_device"):
+        if mesh is not None:
+            rs = _mesh_ops_to_device(rs, mesh, n_arr, jax, jnp)
+        else:
+            rs = _ops_to_device(rs, jax, jnp)
+        state = rs.state
+        if collect:
+            state = state.replace(events=fresh_events(cap))
 
     log = PushLog()
     qs_parts, hs_parts, e_parts = [], [], []
     ci = 0
     while ci < rs.n_chunks:
         t0 = ci * chunk
-        fn = _jax_chunk_fn(n, chunk, T, policy, rs.overhead, collect, cap,
-                           rs.statics, agg, dynamics, mesh=mesh,
-                           n_arr=n_arr)
-        prev = state
-        state, (qs, hs, esum) = fn(rs.tables, rs.app_sched, rs.app_choice,
-                                   rs.scalars, rs.pol_ops, rs.agg_ops,
-                                   rs.dyn_ops, jnp.asarray(t0, i), state)
+        m = min(chunk, T - t0)          # live slots (tail chunk is padded)
+        with TraceAnnotation("scan.chunk", t0=t0, live_slots=m, cap=cap):
+            fn = _jax_chunk_fn(n, chunk, T, policy, rs.overhead, collect,
+                               cap, rs.statics, agg, dynamics, mesh=mesh,
+                               n_arr=n_arr)
+            prev = state
+            state, (qs, hs, esum) = fn(
+                rs.tables, rs.app_sched, rs.app_choice, rs.scalars,
+                rs.pol_ops, rs.agg_ops, rs.dyn_ops, jnp.asarray(t0, i),
+                state)
+        # the host waits here while the device runs the chunk
+        with TraceAnnotation("scan.wait"):
+            if collect:
+                cnt = int(state.events.count)
+            else:
+                qs.block_until_ready()
         if collect:
-            cnt = int(state.events.count)
             if cnt > cap:
                 # buffer overflow: double and re-run this chunk from its
                 # saved entry state (count is exact, rows past cap dropped)
                 cap = _next_pow2(cnt)
-                state = prev.replace(events=fresh_events(cap))
+                with TraceAnnotation("scan.overflow", cap=cap):
+                    state = prev.replace(events=fresh_events(cap))
                 continue
-            if cnt:
-                log.extend_rows(np.asarray(state.events.rows[:cnt]))
-            cnt0 = jnp.asarray(0, i)
-            if mesh is not None:
-                cnt0 = jax.device_put(cnt0, rs.repl_sharding)
-            state = state.replace(events=PushBuffer(state.events.rows,
-                                                    cnt0))
-        m = min(chunk, T - t0)          # live slots (tail chunk is padded)
-        qs_parts.append(np.asarray(qs, dtype=float)[:m])
-        hs_parts.append(np.asarray(hs, dtype=float)[:m])
-        e_parts.append(np.asarray(esum, dtype=float)[:m])
+            with TraceAnnotation("scan.drain", pushes=cnt):
+                if cnt:
+                    log.extend_rows(np.asarray(state.events.rows[:cnt]))
+                cnt0 = jnp.asarray(0, i)
+                if mesh is not None:
+                    cnt0 = jax.device_put(cnt0, rs.repl_sharding)
+                state = state.replace(events=PushBuffer(state.events.rows,
+                                                        cnt0))
+        with TraceAnnotation("scan.traces"):
+            qs_parts.append(np.asarray(qs, dtype=float)[:m])
+            hs_parts.append(np.asarray(hs, dtype=float)[:m])
+            e_parts.append(np.asarray(esum, dtype=float)[:m])
         ci += 1
 
-    # where the scan's per-user carry and arrival operands actually lived
-    placement = {
-        name: (tuple(sorted(d.id for d in x.sharding.device_set)),
-               x.sharding.shard_shape(x.shape))
-        for name, x in (("state.mode", state.mode),
-                        ("state.energy", state.energy),
-                        ("app_sched", rs.app_sched))}
-    # the run's final state, readable on the host like the other engines'
-    host = _state_to_host(state, jax)
-    if mesh is not None and n_arr != n:
-        host = unpad_state_per_user(host, n)     # pad rows are all-zero
-    sim.state = host
-    if mesh is None:
-        energy_total = float(jnp.sum(state.energy))
-    else:
-        # device reduction order differs across shards anyway; sum the
-        # unpadded host rows (pads contribute exact 0.0 either way)
-        energy_total = float(np.sum(host.energy))
-    updates_total = int(np.sum(host.updates))
-    sum_Q, sum_H = float(state.sum_Q), float(state.sum_H)
-    corun_updates = int(state.corun_updates)
-    idx = np.arange(0, T, cfg.trace_every)
-    if qs_parts:
-        qs = np.concatenate(qs_parts)
-        hs = np.concatenate(hs_parts)
-        es = np.concatenate(e_parts)
-    else:
-        qs = hs = es = np.zeros(0)
-    return SimResult(
-        energy_j=energy_total,
-        updates=updates_total,
-        trace_t=idx.copy(), trace_energy=es[idx],
-        trace_Q=qs[idx], trace_H=hs[idx],
-        push_log=log, accuracy=[],
-        mean_Q=sum_Q / T if T else 0.0,
-        mean_H=sum_H / T if T else 0.0,
-        corun_fraction=corun_updates / max(updates_total, 1),
-        drops=dynamics.total_drops(sim.state.dyn),
-        placement=placement)
+    with TraceAnnotation("scan.finish"):
+        # where the scan's per-user carry and arrival operands actually lived
+        placement = {
+            name: (tuple(sorted(d.id for d in x.sharding.device_set)),
+                   x.sharding.shard_shape(x.shape))
+            for name, x in (("state.mode", state.mode),
+                            ("state.energy", state.energy),
+                            ("app_sched", rs.app_sched))}
+        # the run's final state, readable on the host like the other engines'
+        host = _state_to_host(state, jax)
+        if mesh is not None and n_arr != n:
+            host = unpad_state_per_user(host, n)     # pad rows are all-zero
+        sim.state = host
+        if mesh is None:
+            energy_total = float(jnp.sum(state.energy))
+        else:
+            # device reduction order differs across shards anyway; sum the
+            # unpadded host rows (pads contribute exact 0.0 either way)
+            energy_total = float(np.sum(host.energy))
+        updates_total = int(np.sum(host.updates))
+        sum_Q, sum_H = float(state.sum_Q), float(state.sum_H)
+        corun_updates = int(state.corun_updates)
+        idx = np.arange(0, T, cfg.trace_every)
+        if qs_parts:
+            qs = np.concatenate(qs_parts)
+            hs = np.concatenate(hs_parts)
+            es = np.concatenate(e_parts)
+        else:
+            qs = hs = es = np.zeros(0)
+        return SimResult(
+            energy_j=energy_total,
+            updates=updates_total,
+            trace_t=idx.copy(), trace_energy=es[idx],
+            trace_Q=qs[idx], trace_H=hs[idx],
+            push_log=log, accuracy=[],
+            mean_Q=sum_Q / T if T else 0.0,
+            mean_H=sum_H / T if T else 0.0,
+            corun_fraction=corun_updates / max(updates_total, 1),
+            drops=dynamics.total_drops(sim.state.dyn),
+            placement=placement)
 
 
 # ======================================================================
@@ -1291,95 +1315,114 @@ def run_jax_sweep(sims) -> List[SimResult]:
             "run_jax_sweep needs sims sharing one sweep_bucket_key; got "
             f"{len(keys)} distinct keys (None = jax/vmap-ineligible). "
             "Use core.scenario.run_sweep for bucketing + fallback.")
-    if len(sims) == 1:
-        return [_run_jax(sims[0])]
+    cfg = sims[0].cfg
+    with TraceAnnotation("sim.run", engine="jax_sweep", n_users=cfg.n_users,
+                         slots=n_slots(cfg), batch=len(sims)):
+        if len(sims) == 1:
+            return [_run_jax(sims[0])]
+        return _run_jax_batch(sims, jax, jnp)
+
+
+def _run_jax_batch(sims, jax, jnp) -> List[SimResult]:
+    """``run_jax_sweep``'s vmapped path for two or more sims."""
     B = len(sims)
     policy, agg = sims[0].policy, sims[0].agg
     dynamics = sims[0].dynamics
-    preps = [_jax_run_setup(s, jax, jnp) for s in sims]
-    p0 = preps[0]
-    n, T, chunk, collect, f, i = p0.n, p0.T, p0.chunk, p0.collect, \
-        p0.f, p0.i
 
     # stack HOST-side (the setups are numpy), then device-put the whole
     # batch in one pass — one transfer per leaf, independent of B
     def stack(parts):
         return jax.tree.map(lambda *xs: np.stack(xs), *parts)
 
-    rs = SimpleNamespace(
-        tables=stack([p.tables for p in preps]),
-        app_sched=np.stack([p.app_sched for p in preps]),
-        app_choice=np.stack([p.app_choice for p in preps]),
-        scalars=stack([p.scalars for p in preps]),
-        pol_ops=stack([p.pol_ops for p in preps]),
-        agg_ops=stack([p.agg_ops for p in preps]),
-        dyn_ops=stack([p.dyn_ops for p in preps]),
-        state=stack([p.state for p in preps]))
-    rs = _ops_to_device(rs, jax, jnp)
+    with TraceAnnotation("scan.setup"):
+        preps = [_jax_run_setup(s, jax, jnp) for s in sims]
+        rs = SimpleNamespace(
+            tables=stack([p.tables for p in preps]),
+            app_sched=np.stack([p.app_sched for p in preps]),
+            app_choice=np.stack([p.app_choice for p in preps]),
+            scalars=stack([p.scalars for p in preps]),
+            pol_ops=stack([p.pol_ops for p in preps]),
+            agg_ops=stack([p.agg_ops for p in preps]),
+            dyn_ops=stack([p.dyn_ops for p in preps]),
+            state=stack([p.state for p in preps]))
+    p0 = preps[0]
+    n, T, chunk, collect, f, i = p0.n, p0.T, p0.chunk, p0.collect, \
+        p0.f, p0.i
+    cap = p0.cap
+    with TraceAnnotation("scan.to_device"):
+        rs = _ops_to_device(rs, jax, jnp)
+        state = rs.state
+        if collect:
+            state = state.replace(events=PushBuffer(
+                jnp.zeros((B, cap, 6), f), jnp.zeros((B,), i)))
     tables, app_sched, app_choice = rs.tables, rs.app_sched, rs.app_choice
     scalars, pol_ops, agg_ops, dyn_ops = \
         rs.scalars, rs.pol_ops, rs.agg_ops, rs.dyn_ops
-    state = rs.state
-    cap = p0.cap
-    if collect:
-        state = state.replace(events=PushBuffer(
-            jnp.zeros((B, cap, 6), f), jnp.zeros((B,), i)))
 
     logs = [PushLog() for _ in range(B)]
     qs_parts, hs_parts, e_parts = [], [], []
     ci = 0
     while ci < p0.n_chunks:
         t0 = ci * chunk
-        fn = _jax_chunk_fn(n, chunk, T, policy, p0.overhead, collect, cap,
-                           p0.statics, agg, dynamics, batch=B)
-        prev = state
-        state, (qs, hs, esum) = fn(tables, app_sched, app_choice, scalars,
-                                   pol_ops, agg_ops, dyn_ops,
-                                   jnp.asarray(t0, i), state)
+        m = min(chunk, T - t0)          # live slots (tail chunk is padded)
+        with TraceAnnotation("scan.chunk", t0=t0, live_slots=m, cap=cap):
+            fn = _jax_chunk_fn(n, chunk, T, policy, p0.overhead, collect,
+                               cap, p0.statics, agg, dynamics, batch=B)
+            prev = state
+            state, (qs, hs, esum) = fn(tables, app_sched, app_choice,
+                                       scalars, pol_ops, agg_ops, dyn_ops,
+                                       jnp.asarray(t0, i), state)
+        with TraceAnnotation("scan.wait"):
+            if collect:
+                counts = np.asarray(state.events.count)
+            else:
+                qs.block_until_ready()
         if collect:
-            counts = np.asarray(state.events.count)
             if int(counts.max()) > cap:
                 # any config overflowing re-runs the whole chunk with
                 # the buffer doubled for every row (counts stay exact)
                 cap = _next_pow2(int(counts.max()))
-                state = prev.replace(events=PushBuffer(
-                    jnp.zeros((B, cap, 6), f), jnp.zeros((B,), i)))
+                with TraceAnnotation("scan.overflow", cap=cap):
+                    state = prev.replace(events=PushBuffer(
+                        jnp.zeros((B, cap, 6), f), jnp.zeros((B,), i)))
                 continue
-            rows = np.asarray(state.events.rows)
-            for b in range(B):
-                if counts[b]:
-                    logs[b].extend_rows(rows[b, :counts[b]])
-            state = state.replace(events=PushBuffer(
-                state.events.rows, jnp.zeros((B,), i)))
-        m = min(chunk, T - t0)          # live slots (tail chunk is padded)
-        qs_parts.append(np.asarray(qs, dtype=float)[:, :m])
-        hs_parts.append(np.asarray(hs, dtype=float)[:, :m])
-        e_parts.append(np.asarray(esum, dtype=float)[:, :m])
+            with TraceAnnotation("scan.drain", pushes=int(counts.sum())):
+                rows = np.asarray(state.events.rows)
+                for b in range(B):
+                    if counts[b]:
+                        logs[b].extend_rows(rows[b, :counts[b]])
+                state = state.replace(events=PushBuffer(
+                    state.events.rows, jnp.zeros((B,), i)))
+        with TraceAnnotation("scan.traces"):
+            qs_parts.append(np.asarray(qs, dtype=float)[:, :m])
+            hs_parts.append(np.asarray(hs, dtype=float)[:, :m])
+            e_parts.append(np.asarray(esum, dtype=float)[:, :m])
         ci += 1
 
-    qs = np.concatenate(qs_parts, axis=1)
-    hs = np.concatenate(hs_parts, axis=1)
-    es = np.concatenate(e_parts, axis=1)
-    # per-config energy reduced on device along the user axis, like the
-    # per-point path's jnp.sum over (n,)
-    energy_rows = np.asarray(jnp.sum(state.energy, axis=1), dtype=float)
-    # one bulk device->host transfer for the whole batch, then numpy
-    # slicing per row — per-row device slicing cost ~50x more here
-    host_all = jax.tree.map(np.asarray, state.replace(events=None))
-    results = []
-    for b, sim in enumerate(sims):
-        host = _state_to_host(jax.tree.map(lambda x: x[b], host_all), jax)
-        sim.state = host
-        sim._ran = True                 # Scenario.run() re-entrancy flag
-        updates_total = int(host.updates.sum())
-        idx = np.arange(0, T, sim.cfg.trace_every)
-        results.append(SimResult(
-            energy_j=float(energy_rows[b]),
-            updates=updates_total,
-            trace_t=idx.copy(), trace_energy=es[b, idx],
-            trace_Q=qs[b, idx], trace_H=hs[b, idx],
-            push_log=logs[b], accuracy=[],
-            mean_Q=host.sum_Q / T, mean_H=host.sum_H / T,
-            corun_fraction=host.corun_updates / max(updates_total, 1),
-            drops=sim.dynamics.total_drops(host.dyn)))
-    return results
+    with TraceAnnotation("scan.finish"):
+        qs = np.concatenate(qs_parts, axis=1)
+        hs = np.concatenate(hs_parts, axis=1)
+        es = np.concatenate(e_parts, axis=1)
+        # per-config energy reduced on device along the user axis, like the
+        # per-point path's jnp.sum over (n,)
+        energy_rows = np.asarray(jnp.sum(state.energy, axis=1), dtype=float)
+        # one bulk device->host transfer for the whole batch, then numpy
+        # slicing per row — per-row device slicing cost ~50x more here
+        host_all = jax.tree.map(np.asarray, state.replace(events=None))
+        results = []
+        for b, sim in enumerate(sims):
+            host = _state_to_host(jax.tree.map(lambda x: x[b], host_all), jax)
+            sim.state = host
+            sim._ran = True                 # Scenario.run() re-entrancy flag
+            updates_total = int(host.updates.sum())
+            idx = np.arange(0, T, sim.cfg.trace_every)
+            results.append(SimResult(
+                energy_j=float(energy_rows[b]),
+                updates=updates_total,
+                trace_t=idx.copy(), trace_energy=es[b, idx],
+                trace_Q=qs[b, idx], trace_H=hs[b, idx],
+                push_log=logs[b], accuracy=[],
+                mean_Q=host.sum_Q / T, mean_H=host.sum_H / T,
+                corun_fraction=host.corun_updates / max(updates_total, 1),
+                drops=sim.dynamics.total_drops(host.dyn)))
+        return results
