@@ -5,17 +5,19 @@ batched per-user state arrays — the run's ``EngineState``
 (core/engine_state.py): mode, cooldown, app id, app/train remaining,
 pulled-at version, energy, idle gap all live in ``(n_users,)`` NumPy
 arrays, and the fleet's catalog is flattened into ``(n_devices, n_apps)``
-lookup tables (``FleetSpec.tables``) gathered per user once at startup.
+lookup tables (``FleetSpec.tables``) gathered per user once at startup;
+the jax slot step selects each user's entry from these per-user tables by
+app column (a where-chain over the app axis, no per-slot gather).
 Every phase of a slot — app arrivals, cooldown transitions, policy
 decisions, training progression, Eq. (10) energy accounting, Eq. (15)/(16)
 queue updates — is a handful of vector ops instead of an O(n) Python loop.
 
 Policy dispatch is pluggable (core/policies.py): the engine exposes the
 shared state as ``eng.s`` (an ``EngineState``) plus per-slot masks and
-catalog gathers, threads the policy's carry pytree
-(``Policy.init_carry``), and calls the ``decide_vectorized`` hook once per
-slot; registered paper policies and any custom policy with the hook run
-here unmodified.
+each user's table entries for its current app, threads the policy's
+carry pytree (``Policy.init_carry``), and calls the
+``decide_vectorized`` hook once per slot; registered paper policies and
+any custom policy with the hook run here unmodified.
 
 Real-ML runs are batched too (core/realml.py): with an ``ml_backend`` the
 engine snapshots pulls per starting cohort (``pull_batch``, at the
@@ -58,10 +60,11 @@ engines; in f32, user ids stay exact up to 2**24.
 
 ``SimConfig.n_devices`` > 0 shards the SAME chunked scan over a 1-D
 ``("users",)`` mesh (launch/mesh.py ``make_sim_mesh``) via GSPMD
-constraint steering: per-user EngineState leaves, catalog gathers and
-arrival columns carry ``PartitionSpec("users")`` constraints, scheduler
-scalars stay replicated, and XLA's SPMD partitioner inserts the
-collectives. Bit-consistency with the single-device scan is by
+constraint steering: per-user EngineState leaves, the per-user tables
+(and the app columns the slot step selects from) and arrival columns
+carry ``PartitionSpec("users")`` constraints, scheduler scalars stay
+replicated, and XLA's SPMD partitioner inserts the collectives.
+Bit-consistency with the single-device scan is by
 construction, not luck: every input of the POLICY DECISION phase is
 constrained replicated before the ``scan_step`` hook runs, so Alg. 2's
 float reductions (Eq. 16's gap sum feeding H) compile to the exact
@@ -573,6 +576,11 @@ def _build_jax_chunk_fn(n: int, chunk: int, T: int, policy, overhead: bool,
         sched_c = lax.dynamic_slice(app_sched, (t0, 0), (chunk, n_arr))
         choice_c = lax.dynamic_slice(app_choice, (t0, 0), (chunk, n_arr))
         ts = t0 + jnp.arange(chunk)
+        # the per-user app tables as loop-invariant (n_arr,) app columns:
+        # the slot step selects each user's entry by app id from these
+        # (a where-chain, exact), never a per-element gather
+        app_cols = [[tab[:, k] for k in range(tab.shape[-1])]
+                    for tab in (T_COR, P_APP, P_COR)]
 
         if n_arr == n:
             def pad_users(x, fill):
@@ -661,9 +669,12 @@ def _build_jax_chunk_fn(n: int, chunk: int, T: int, policy, overhead: bool,
                 app_rem = jnp.where(ended, 0.0, app_rem)
                 app = jnp.where(new_app, crow, app)
                 aid = jnp.maximum(app, 0)
-                tcor_g = T_COR[ar, aid]
-                papp_g = P_APP[ar, aid]
-                pcor_g = P_COR[ar, aid]
+                sel = [cols[0] for cols in app_cols]
+                for k in range(1, len(app_cols[0])):
+                    is_k = aid == k
+                    sel = [jnp.where(is_k, cols[k], g)
+                           for cols, g in zip(app_cols, sel)]
+                tcor_g, papp_g, pcor_g = sel
                 app_rem = jnp.where(new_app, tcor_g, app_rem)
 
             # cooldown -> waiting
