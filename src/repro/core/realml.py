@@ -31,6 +31,15 @@ single-HBM-pass ``fused_apply_2d`` Pallas kernel — the per-push momentum
 norm chains through the scan carry as a scalar instead of re-traversing
 the pytree.
 
+Reset contract: ``FederatedSim.run()`` calls ``reset()`` on its backend
+before every run after the first, and a backend's ``reset()`` puts it back
+where its constructor left it — initial parameters, zero momentum, server
+version 0, no in-flight pulls, every client's key chain and permutation
+bank from the start. Two runs of one simulator are then identical (push
+log, accuracy trace, final parameters) and the second compiles nothing:
+every jitted program here, the accuracy one included, is module-level and
+keyed on shapes and static model functions, never on a backend instance.
+
 Equivalence contract (pinned by tests/test_real_mode.py): under the
 paper's queue regime (L_b large enough that H stays 0, where the online
 decision is independent of the momentum norm) the batched path reproduces
@@ -47,6 +56,7 @@ from typing import Dict, Optional, Type, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.aggregation import (AggregationRule, ReplaceRule,
                                     aggregation_support)
@@ -65,11 +75,12 @@ from repro.models.mlp import init_mlp, mlp_logits, mlp_loss
 class BatchedMLBackend:
     """Protocol for batched real-ML coupling (vectorized-engine capable).
 
-    A backend instance is single-run state: it owns the parameter server,
-    the per-client data, and the pulled-parameter snapshots of every
-    in-flight user. The vectorized engine drives it with whole cohorts;
-    the loop oracle drives the same instance through ``hooks()``. Construct
-    a fresh backend per run (server state is consumed by a run).
+    A backend instance owns the parameter server, the per-client data, and
+    the pulled-parameter snapshots of every in-flight user. The vectorized
+    engine drives it with whole cohorts; the loop oracle drives the same
+    instance through ``hooks()``. A run consumes the server state;
+    ``reset()`` restores it, and ``FederatedSim.run()`` calls it before
+    every run after the first.
 
     Attributes engines rely on: ``n_users`` (validated against
     ``SimConfig.n_users``), ``sync`` (FedAvg lock-step vs async parameter
@@ -95,6 +106,15 @@ class BatchedMLBackend:
         the fleet to derive device-class scales, and the config is
         forwarded to the rule's ``scan_operands``/``init_carry`` on the
         fused push-scan path; the default is a no-op."""
+
+    def reset(self) -> None:
+        """Return to the state the constructor left: the initial global
+        parameters and momentum, version 0, nothing in flight, and every
+        random stream from its start, so that the next run repeats the
+        first. A backend that cannot do this runs once per simulator."""
+        raise NotImplementedError(
+            f"{type(self).__name__} has no reset(); build a new simulator "
+            "for each run")
 
     # ------------------------------------------------------------ batched path
     def pull_batch(self, uids: np.ndarray, version: int) -> None:
@@ -207,14 +227,15 @@ def _masked_epoch(params, idx, mask, flat_x, flat_y, eta, beta, loss_fn):
     def step(carry, xs):
         p, v = carry
         x, y, m = xs
-        grads, _ = jax.grad(
-            lambda q: loss_fn(q, {"images": x, "labels": y}),
-            has_aux=True)(p)
-        v2 = jax.tree.map(lambda vv, g: beta * vv + (1 - beta) * g,
-                          v, grads)
-        p2 = jax.tree.map(lambda pp, vv: pp - eta * vv, p, v2)
-        p = jax.tree.map(lambda a, b: jnp.where(m, a, b), p2, p)
-        v = jax.tree.map(lambda a, b: jnp.where(m, a, b), v2, v)
+        with jax.named_scope("ml.local_step"):
+            grads, _ = jax.grad(
+                lambda q: loss_fn(q, {"images": x, "labels": y}),
+                has_aux=True)(p)
+            v2 = jax.tree.map(lambda vv, g: beta * vv + (1 - beta) * g,
+                              v, grads)
+            p2 = jax.tree.map(lambda pp, vv: pp - eta * vv, p, v2)
+            p = jax.tree.map(lambda a, b: jnp.where(m, a, b), p2, p)
+            v = jax.tree.map(lambda a, b: jnp.where(m, a, b), v2, v)
         return (p, v), None
 
     (params, _), _ = jax.lax.scan(step, (params, v0), (bx, by, mask))
@@ -267,6 +288,15 @@ def _train_chunk(params, idx, mask, flat_x, flat_y, eta, beta, shared,
         lambda p, i, m: _masked_epoch(p, i, m, flat_x, flat_y, eta, beta,
                                       loss_fn)
     )(_lanes(params, idx, shared), idx, mask)
+
+
+@functools.partial(jax.jit, static_argnames=("logits_fn",))
+def _accuracy(params, test_x, test_y, logits_fn):
+    """Test accuracy of ``params``: one program per model (``logits_fn``,
+    a module-level function) and test-set shape, shared by every backend
+    and every run."""
+    logits = logits_fn(params, test_x)
+    return jnp.mean((jnp.argmax(logits, -1) == test_y).astype(jnp.float32))
 
 
 _FINISH_FN_CACHE: dict = {}
@@ -370,10 +400,12 @@ def _build_finish_chunk(rule, eta, beta, shared, need_gaps, loss_fn,
             v = jax.tree.map(lambda a, b: jnp.where(ok, a, b), v2, v)
             return (p, v), (vnorm_pre, w)
 
-        (p_out, v_out), (vnorms, ws) = jax.lax.scan(
-            push_step, (server_params, server_v),
-            (trained, valid, lags, uids))
-        return p_out, v_out, vnorms, ws, _tree_l2_norm_traced(v_out)
+        with jax.named_scope("ml.apply"):
+            (p_out, v_out), (vnorms, ws) = jax.lax.scan(
+                push_step, (server_params, server_v),
+                (trained, valid, lags, uids))
+            vn_out = _tree_l2_norm_traced(v_out)
+        return p_out, v_out, vnorms, ws, vn_out
 
     return finish
 
@@ -398,70 +430,71 @@ def _build_finish_chunk_pallas(rule, eta, beta, shared, need_norms,
                                           beta, loss_fn)
         )(_lanes(params, idx, shared), idx, mask)
 
-        # ---- flatten ONCE per chunk to the kernel's (rows, 128) layout
-        leaves = jax.tree.leaves(server_params)
-        treedef = jax.tree.structure(server_params)
-        shapes = [l.shape for l in leaves]
-        sizes = [l.size for l in leaves]
-        n_tot = sum(sizes)
-        block_rows = clamp_block_rows(n_tot)
-        per_block = block_rows * LANES
-        padded = -(-n_tot // per_block) * per_block
-        rows = padded // LANES
+        with jax.named_scope("ml.apply"):
+            # ---- flatten ONCE per chunk to the kernel's (rows, 128) layout
+            leaves = jax.tree.leaves(server_params)
+            treedef = jax.tree.structure(server_params)
+            shapes = [l.shape for l in leaves]
+            sizes = [l.size for l in leaves]
+            n_tot = sum(sizes)
+            block_rows = clamp_block_rows(n_tot)
+            per_block = block_rows * LANES
+            padded = -(-n_tot // per_block) * per_block
+            rows = padded // LANES
 
-        def flat2d(tree):
-            f = jnp.concatenate([l.reshape(-1).astype(jnp.float32)
-                                 for l in jax.tree.leaves(tree)])
-            return jnp.pad(f, (0, padded - n_tot)).reshape(rows, LANES)
+            def flat2d(tree):
+                f = jnp.concatenate([l.reshape(-1).astype(jnp.float32)
+                                     for l in jax.tree.leaves(tree)])
+                return jnp.pad(f, (0, padded - n_tot)).reshape(rows, LANES)
 
-        p2 = flat2d(server_params)
-        v2 = flat2d(server_v)
-        # trained lanes: (C, rows, 128), padded along the flat axis —
-        # padding lanes mix 0 with 0 and add 0 to the norm
-        t2 = jnp.concatenate(
-            [l.reshape(l.shape[0], -1).astype(jnp.float32)
-             for l in jax.tree.leaves(trained)], axis=1)
-        t2 = jnp.pad(t2, ((0, 0), (0, padded - n_tot)))
-        t2 = t2.reshape(t2.shape[0], rows, LANES)
-        # entry sum-of-squares: one reduction per CHUNK; every in-scan
-        # pre-norm after this is carried forward by the kernel
-        sumsq0 = jnp.sum(v2 * v2)
-        inv_eta = 1.0 / eta_s
+            p2 = flat2d(server_params)
+            v2 = flat2d(server_v)
+            # trained lanes: (C, rows, 128), padded along the flat axis —
+            # padding lanes mix 0 with 0 and add 0 to the norm
+            t2 = jnp.concatenate(
+                [l.reshape(l.shape[0], -1).astype(jnp.float32)
+                 for l in jax.tree.leaves(trained)], axis=1)
+            t2 = jnp.pad(t2, ((0, 0), (0, padded - n_tot)))
+            t2 = t2.reshape(t2.shape[0], rows, LANES)
+            # entry sum-of-squares: one reduction per CHUNK; every in-scan
+            # pre-norm after this is carried forward by the kernel
+            sumsq0 = jnp.sum(v2 * v2)
+            inv_eta = 1.0 / eta_s
 
-        def push_step(carry, xs):
-            p, v, sq = carry
-            t_j, ok, lag_j, uid_j = xs
-            vnorm_pre = jnp.sqrt(sq) if need_norms \
-                else jnp.asarray(0.0, jnp.float32)
-            if replace:
-                w = jnp.asarray(1.0, jnp.float32)
-            else:
-                gap_j = _jax_gradient_gap(vnorm_pre, lag_j, eta, beta)
-                pv = SimpleNamespace(jnp=jnp, lag=lag_j, gap=gap_j,
-                                     v_norm=vnorm_pre, users=uid_j,
-                                     consts=agg_ops,
-                                     float_dtype=vnorm_pre.dtype)
-                _, w = rule.scan_weight(agg_carry, pv)
-            mixed, v_new, sq_new = fused_apply_2d(
-                p, v, t_j, w, inv_eta, beta, block_rows=block_rows,
-                interpret=interpret)
-            p = jnp.where(ok, mixed, p)
-            v = jnp.where(ok, v_new, v)
-            sq = jnp.where(ok, sq_new, sq)
-            return (p, v, sq), (vnorm_pre, w)
+            def push_step(carry, xs):
+                p, v, sq = carry
+                t_j, ok, lag_j, uid_j = xs
+                vnorm_pre = jnp.sqrt(sq) if need_norms \
+                    else jnp.asarray(0.0, jnp.float32)
+                if replace:
+                    w = jnp.asarray(1.0, jnp.float32)
+                else:
+                    gap_j = _jax_gradient_gap(vnorm_pre, lag_j, eta, beta)
+                    pv = SimpleNamespace(jnp=jnp, lag=lag_j, gap=gap_j,
+                                         v_norm=vnorm_pre, users=uid_j,
+                                         consts=agg_ops,
+                                         float_dtype=vnorm_pre.dtype)
+                    _, w = rule.scan_weight(agg_carry, pv)
+                mixed, v_new, sq_new = fused_apply_2d(
+                    p, v, t_j, w, inv_eta, beta, block_rows=block_rows,
+                    interpret=interpret)
+                p = jnp.where(ok, mixed, p)
+                v = jnp.where(ok, v_new, v)
+                sq = jnp.where(ok, sq_new, sq)
+                return (p, v, sq), (vnorm_pre, w)
 
-        (p2, v2, sq), (vnorms, ws) = jax.lax.scan(
-            push_step, (p2, v2, sumsq0), (t2, valid, lags, uids))
+            (p2, v2, sq), (vnorms, ws) = jax.lax.scan(
+                push_step, (p2, v2, sumsq0), (t2, valid, lags, uids))
 
-        def unflat(f2):
-            f = f2.reshape(-1)[:n_tot]
-            out, off = [], 0
-            for shp, sz in zip(shapes, sizes):
-                out.append(f[off:off + sz].reshape(shp))
-                off += sz
-            return treedef.unflatten(out)
+            def unflat(f2):
+                f = f2.reshape(-1)[:n_tot]
+                out, off = [], 0
+                for shp, sz in zip(shapes, sizes):
+                    out.append(f[off:off + sz].reshape(shp))
+                    off += sz
+                return treedef.unflatten(out)
 
-        return unflat(p2), unflat(v2), vnorms, ws, jnp.sqrt(sq)
+            return unflat(p2), unflat(v2), vnorms, ws, jnp.sqrt(sq)
 
     return finish
 
@@ -504,6 +537,13 @@ class ImageClassifierBackend(BatchedMLBackend):
     the server's and the fused scan's — through the single-HBM-pass
     Pallas kernel (``kernels/fused_update``); the default ``"auto"``
     keeps the bit-stable reference path off-TPU.
+
+    ``reset()`` restores the constructor's state (the initial parameters
+    it keeps, zero momentum, version 0, each client's key chain from
+    ``PRNGKey(client_id)`` with empty permutation banks) without
+    rebuilding the data, so a simulator repeats its run exactly.
+    ``push_v_norms()`` gives the server momentum norm after each push of
+    the run, as the finish chunk computed it for the next push's gap.
     """
 
     # bound by subclasses: module-level (init, loss, logits) functions
@@ -544,6 +584,8 @@ class ImageClassifierBackend(BatchedMLBackend):
                    beta=beta)
             for i, s in enumerate(shards)]
         params0 = self.model_init(jax.random.PRNGKey(seed))
+        self._params0 = params0
+        self._keys0 = [c._key for c in self.clients]
         self.server: object
         if sync:
             self.server = SyncServer(params0)
@@ -593,18 +635,30 @@ class ImageClassifierBackend(BatchedMLBackend):
         self._perm_bank: list = [None] * n_users
         self._bank_pos = np.zeros(n_users, dtype=np.int64)
         self._bank_epochs = 16
+        # momentum norm after each push (host arrays and lazy device
+        # scalars, in push order), kept where the push log needs the gaps
+        self._norm_parts: list = []
+        self._test_x = jnp.asarray(test_x)
+        self._test_y = jnp.asarray(test_y)
 
-        test_x_j = jnp.asarray(test_x)
-        test_y_j = jnp.asarray(test_y)
-        logits_fn = self.model_logits
+    def reset(self) -> None:
+        with TraceAnnotation("ml.reset"):
+            p0 = self._params0
+            self.server.reset(p0)
+            for c, key in zip(self.clients, self._keys0):
+                c._key = key
+            self._inflight = [p0] * self.n_users
+            self._perm_bank = [None] * self.n_users
+            self._bank_pos[:] = 0
+            self._norm_parts = []
 
-        @jax.jit
-        def _acc(params):
-            logits = logits_fn(params, test_x_j)
-            return jnp.mean((jnp.argmax(logits, -1) == test_y_j)
-                            .astype(jnp.float32))
-
-        self._acc = _acc
+    def push_v_norms(self) -> np.ndarray:
+        """The server momentum norm after each push since the last reset,
+        in push order; empty where the gaps were not asked for."""
+        if not self._norm_parts:
+            return np.zeros(0)
+        return np.concatenate([np.asarray(x, np.float64).reshape(-1)
+                               for x in self._norm_parts])
 
     # ------------------------------------------------------------ loop adapter
     def hooks(self) -> dict:
@@ -724,10 +778,11 @@ class ImageClassifierBackend(BatchedMLBackend):
             return None
         parts = []
         for params, shared, idx, mask, valid, k in self._cohort_chunks(uids):
-            out = _train_chunk(params, idx, mask,
-                               self._flat_x, self._flat_y,
-                               self.eta, self.beta, shared,
-                               self.model_loss)
+            with self._train_span(mask, k):
+                out = _train_chunk(params, idx, mask,
+                                   self._flat_x, self._flat_y,
+                                   self.eta, self.beta, shared,
+                                   self.model_loss)
             parts.append(jax.tree.map(lambda a: a[:k], out))
         if len(parts) == 1:
             return parts[0]
@@ -769,13 +824,18 @@ class ImageClassifierBackend(BatchedMLBackend):
             pos += k
             fn = _finish_chunk_fn(rule, self.eta, self.beta, shared,
                                   need_gaps, self.model_loss, self.kernel)
-            p, v, vn, ws, vn_out = fn(
-                params, idx, mask, valid, jnp.asarray(lag_c),
-                jnp.asarray(uid_c), self._agg_carry, agg_ops, p, v,
-                self._flat_x, self._flat_y)
+            with self._train_span(mask, k):
+                p, v, vn, ws, vn_out = fn(
+                    params, idx, mask, valid, jnp.asarray(lag_c),
+                    jnp.asarray(uid_c), self._agg_carry, agg_ops, p, v,
+                    self._flat_x, self._flat_y)
             if need_gaps:
-                vnorms.append(np.asarray(vn[:k], dtype=np.float64))
-                weights.append(np.asarray(ws[:k], dtype=np.float64))
+                vn_k = np.asarray(vn, dtype=np.float64)[:k]
+                vnorms.append(vn_k)
+                weights.append(np.asarray(ws, dtype=np.float64)[:k])
+                # after push j the norm is push j+1's pre-push norm; after
+                # the chunk's last valid push it is the chunk's final norm
+                self._norm_parts += [vn_k[1:], vn_out]
         self.server.params = p
         self.server._v = v
         # lazy: a 0-d device scalar; v_norm() converts on demand so
@@ -802,6 +862,7 @@ class ImageClassifierBackend(BatchedMLBackend):
             res = self.server.push(uid,
                                    jax.tree.map(lambda a: a[j], trained))
             weights[j] = res.applied_weight
+            self._norm_parts.append(np.asarray(self.v_norm()))
         return gaps, weights
 
     def submit_batch(self, uids, trained, lags, eta, beta):
@@ -819,6 +880,17 @@ class ImageClassifierBackend(BatchedMLBackend):
         # float() realizes the lazy device scalar the fused finish leaves
         # behind; a plain float (eager loop pushes) passes through
         return 0.0 if self.sync else float(self.server.v_norm)
+
+    def _train_span(self, mask, k):
+        """The host span of one chunk's training dispatch: its live lanes,
+        its padded lane count and the samples its live lanes train."""
+        return TraceAnnotation("ml.train", cohort=k, bucket=len(mask),
+                               samples=int(np.count_nonzero(mask))
+                               * self.batch_size)
+
+    def _acc(self, params) -> jax.Array:
+        return _accuracy(params, self._test_x, self._test_y,
+                         self.model_logits)
 
     def evaluate(self) -> float:
         return float(self._acc(self.server.params))

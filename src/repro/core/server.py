@@ -73,7 +73,6 @@ class AsyncParameterServer:
         ``"pallas"`` fuses mix + momentum + norm into one kernel pass,
         ``"reference"`` keeps the multi-traversal jnp path (bit-stable),
         ``"auto"`` = Pallas on TPU, reference elsewhere."""
-        self.params = params
         self.eta = eta
         self.beta = beta
         self.rule: AggregationRule = configure_aggregation(
@@ -82,6 +81,12 @@ class AsyncParameterServer:
         self.aggregation = self.rule.name
         self.fleet_spec = fleet
         self.kernel = resolve_kernel_mode(kernel)
+        self.reset(params)
+
+    def reset(self, params: Any) -> None:
+        """Start over from ``params``: zero momentum, version 0, nothing in
+        flight. The rule and the bound fleet stay."""
+        self.params = params
         self.lag_tracker = LagTracker()
         self._v = jax.tree.map(jnp.zeros_like, params)
         self.v_norm = 0.0
@@ -137,6 +142,10 @@ class SyncServer:
     """FedAvg (McMahan et al.): lock-step rounds, average over the cohort."""
 
     def __init__(self, params: Any):
+        self.reset(params)
+
+    def reset(self, params: Any) -> None:
+        """Start over from ``params`` at round 0 with nothing submitted."""
         self.params = params
         self.round = 0
         self._pending: list[Any] = []

@@ -603,10 +603,13 @@ class FederatedSim:
                 # a run consumes the mutable EngineState / UserState
                 # objects; reallocate them so repeated run() calls
                 # (warmup-then-timed patterns) start fresh instead of
-                # continuing silently from the previous run's state.
-                # Real-ML backends/hook closures are single-run by
-                # contract and are NOT reset here.
+                # continuing silently from the previous run's state. A
+                # real-ML backend is reset too (core/realml.py), so the
+                # run repeats the first; bare ml_hooks dicts have no
+                # reset and carry their state over.
                 with TraceAnnotation("sim.reset"):
+                    if self.ml_backend is not None:
+                        self.ml_backend.reset()
                     self.state = EngineState.init(
                         self.cfg.n_users, self.cfg, self.policy,
                         agg=self.agg, fleet=self.fleet_spec,

@@ -25,7 +25,8 @@ EngineState's global version) and, when a slot's trainers finish,
 dispatches ONE vmap'd local-train over the whole finisher cohort followed
 by ordered server pushes (``_finish_cohort``) — instead of the loop
 engine's n Python callbacks. Accuracy is sampled on the same cadence as
-the loop oracle.
+the loop oracle. The engine's calls into the backend open host spans
+(``ml.pull``, ``ml.finish``, ``ml.eval``) inside the run's ``sim.run``.
 
 Equivalence contract: seeded runs reproduce the reference loop engine
 (``FederatedSim._run_loop``) — identical decision sequences, update counts,
@@ -226,7 +227,8 @@ class _NumpyEngine:
         s.pulled_at[idx] = s.version
         s.in_flight += len(idx)
         if self.backend is not None:
-            self.backend.pull_batch(np.asarray(idx), s.version)
+            with TraceAnnotation("ml.pull", cohort=len(idx)):
+                self.backend.pull_batch(np.asarray(idx), s.version)
 
     def run(self) -> SimResult:
         cfg = self.cfg
@@ -356,7 +358,8 @@ class _NumpyEngine:
                         s.version += k
                     if self.backend is not None:
                         # one vmap'd local-train + ordered server pushes
-                        gaps, weights = self._finish_cohort(fidx, lags)
+                        with TraceAnnotation("ml.finish", pushes=k):
+                            gaps, weights = self._finish_cohort(fidx, lags)
                     s.updates[fidx] += 1
                     mode[fidx] = MODE_COOL
                     s.cooldown[fidx] = cfg.ready_delay if not dyn_active \
@@ -396,10 +399,12 @@ class _NumpyEngine:
                 trace_Q.append(s.Q)
                 trace_H.append(s.H)
             if eval_every and t % eval_every == 0 and t > 0:
-                accuracy.append((t, self.backend.evaluate()))
+                with TraceAnnotation("ml.eval"):
+                    accuracy.append((t, self.backend.evaluate()))
 
         if self.backend is not None:
-            accuracy.append((T, self.backend.evaluate()))
+            with TraceAnnotation("ml.eval"):
+                accuracy.append((T, self.backend.evaluate()))
         updates_total = int(s.updates.sum())
         return SimResult(
             energy_j=float(s.energy.sum()),

@@ -10,21 +10,29 @@ import jax.numpy as jnp
 
 
 def init_lenet(key, num_classes: int = 10, in_channels: int = 3):
+    """float32 parameters, with or without ``jax_enable_x64``: the images
+    are float32, and the model computes in their precision."""
     ks = jax.random.split(key, 5)
+    f32 = jnp.float32
 
     def conv_init(k, kh, kw, cin, cout):
         std = (kh * kw * cin) ** -0.5
-        return std * jax.random.truncated_normal(k, -3, 3, (kh, kw, cin, cout))
+        return std * jax.random.truncated_normal(k, -3, 3, (kh, kw, cin, cout),
+                                                 f32)
 
     def fc_init(k, din, dout):
-        return din ** -0.5 * jax.random.truncated_normal(k, -3, 3, (din, dout))
+        return din ** -0.5 * jax.random.truncated_normal(k, -3, 3, (din, dout),
+                                                         f32)
+
+    def zeros(n):
+        return jnp.zeros(n, f32)
 
     return {
-        "conv1": {"w": conv_init(ks[0], 5, 5, in_channels, 6), "b": jnp.zeros(6)},
-        "conv2": {"w": conv_init(ks[1], 5, 5, 6, 16), "b": jnp.zeros(16)},
-        "fc1": {"w": fc_init(ks[2], 16 * 5 * 5, 120), "b": jnp.zeros(120)},
-        "fc2": {"w": fc_init(ks[3], 120, 84), "b": jnp.zeros(84)},
-        "fc3": {"w": fc_init(ks[4], 84, num_classes), "b": jnp.zeros(num_classes)},
+        "conv1": {"w": conv_init(ks[0], 5, 5, in_channels, 6), "b": zeros(6)},
+        "conv2": {"w": conv_init(ks[1], 5, 5, 6, 16), "b": zeros(16)},
+        "fc1": {"w": fc_init(ks[2], 16 * 5 * 5, 120), "b": zeros(120)},
+        "fc2": {"w": fc_init(ks[3], 120, 84), "b": zeros(84)},
+        "fc3": {"w": fc_init(ks[4], 84, num_classes), "b": zeros(num_classes)},
     }
 
 
