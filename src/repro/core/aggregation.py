@@ -7,7 +7,7 @@ into a registry object mirroring the ``Policy`` carry protocol
 (core/policies.py), so staleness-aware aggregation is visible to EVERY
 layer of the push path — the loop oracle's ``AsyncParameterServer``, the
 vectorized engine's in-slot push replay, the jax engine's ``lax.scan``
-push scatter, and the fused train+push scan of
+push-log write, and the fused train+push scan of
 ``realml.BatchedMLBackend`` — instead of living as an if/elif ladder
 inside the server.
 

@@ -22,9 +22,9 @@ corun, applied aggregation weight), and the ``SimResult.push_log`` dict
 schema is decoded lazily on access, so fleet-scale runs never materialize
 O(pushes) Python dicts unless the caller actually walks the log. Inside
 the jax scan the same six columns live in a preallocated ``PushBuffer``
-``(capacity, 6)`` array filled by scatter; ``vector_engine`` drains it
-chunk-by-chunk over the horizon, so peak memory stays O(chunk), never
-O(T * n).
+``(capacity + K, 6)`` array written in ``K``-row blocks;
+``vector_engine`` drains it chunk-by-chunk over the horizon, so peak
+memory stays O(chunk), never O(T * n).
 """
 from __future__ import annotations
 
@@ -47,11 +47,14 @@ EVENT_FIELDS = ("t", "user", "lag", "gap", "corun", "weight")
 
 
 class PushBuffer(NamedTuple):
-    """Fixed-width in-scan event buffer: ``rows`` is ``(capacity, 6)`` in
-    ``EVENT_FIELDS`` order, ``count`` the number of pushes recorded so far
-    (monotone within a chunk; entries past capacity are dropped by the
-    scatter, which the driver detects as ``count > capacity`` and retries
-    the chunk with a doubled buffer). NamedTuple => a native jax pytree."""
+    """Fixed-width in-scan event buffer: ``rows`` is ``(capacity + K, 6)``
+    in ``EVENT_FIELDS`` order, ``count`` the number of pushes recorded so
+    far (monotone within a chunk). The slot step writes its finishers at
+    the cursor ``count`` in contiguous blocks of ``K`` rows; a block that
+    starts at or past ``capacity`` lands in the ``K`` slack rows, never on
+    a row below ``capacity``, and the host loop detects the overflow as
+    ``count > capacity`` and retries the chunk with a doubled buffer.
+    NamedTuple => a native jax pytree."""
 
     rows: Any
     count: Any

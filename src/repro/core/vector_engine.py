@@ -47,14 +47,16 @@ the ``scan_step`` carry hook — all registry policies, including offline
 counters carried through the scan); others stay on the numpy path.
 
 Push logs stream out of the scan through a fixed-width event buffer
-(``engine_state.PushBuffer``): each finishing user scatters one
+(``engine_state.PushBuffer``): each finishing user writes one
 ``(t, user, lag, gap, corun, weight)`` row at the buffer cursor — the
 ``weight`` column is the aggregation rule's applied mixing weight
 (core/aggregation.py, ``SimConfig.aggregation``), computed in-jit through
 the rule's ``scan_weight`` hook with its carry riding in
-``EngineState.agg_carry`` — the host drains
-and resets the buffer after every chunk, and an overflowing chunk is
-re-run with a doubled buffer (``count`` always records the true push
+``EngineState.agg_carry``. A slot with finishers sorts them to the front
+in user order and writes their rows as contiguous blocks of ``K`` rows
+(``_push_block``); a slot without any does no push-log work. The host
+drains and resets the buffer after every chunk, and an overflowing chunk
+is re-run with a doubled buffer (``count`` always records the true push
 total) — so ``collect_push_log=True`` costs O(chunk) memory at any fleet
 size, never O(T * n). Enable jax x64 for f64 parity with the numpy
 engines; in f32, user ids stay exact up to 2**24.
@@ -478,8 +480,8 @@ def _jax_chunk_fn(n: int, chunk: int, T: int, policy, overhead: bool,
     the same shape NEVER alias. The policy's ``scan_step`` hook supplies
     the decision block and the rule's ``scan_weight`` the push-log
     weight column; everything else — arrivals, cooldowns, training
-    progression, Eq. 10 energy, Eq. 15/16 queues, the push-event scatter
-    — is engine code shared by every policy."""
+    progression, Eq. 10 energy, Eq. 15/16 queues, the push-log write —
+    is engine code shared by every policy."""
     if agg is None:
         from .aggregation import resolve_aggregation
         agg = resolve_aggregation("replace")
@@ -508,6 +510,21 @@ def _jax_chunk_fn(n: int, chunk: int, T: int, policy, overhead: bool,
     return fn
 
 
+def _push_block(n_arr: int) -> int:
+    """Rows in one block of the slot step's push-log write. A slot with
+    ``kfin`` finishers writes ``ceil(kfin / K)`` blocks, so the buffer
+    carries ``K`` slack rows past its capacity for the last block."""
+    return max(1, min(int(n_arr), 4096))
+
+
+def _drain_counts(ts, K: int) -> dict:
+    """``scan.drain``'s counters from the drained ``t`` columns of a chunk
+    (one per config): ``push_slots``, the slots that wrote rows, and
+    ``blocks``, the ``K``-row blocks the slot step wrote for them."""
+    k = np.concatenate([np.unique(t, return_counts=True)[1] for t in ts])
+    return {"push_slots": int(len(k)), "blocks": int(np.sum(-(-k // K)))}
+
+
 def _build_jax_chunk_fn(n: int, chunk: int, T: int, policy, overhead: bool,
                         collect: bool, capacity: int, statics: tuple = (),
                         agg=None, dynamics=None, batch: int = 0,
@@ -531,6 +548,7 @@ def _build_jax_chunk_fn(n: int, chunk: int, T: int, policy, overhead: bool,
     # per leaf — all identity on the unsharded build, whose traced graph
     # stays byte-identical to the historical one
     n_arr = int(n_arr) or n
+    K = _push_block(n_arr)
     if mesh is not None:
         if batch:
             raise ValueError("sharded chunks never batch: the mesh IS the "
@@ -601,6 +619,54 @@ def _build_jax_chunk_fn(n: int, chunk: int, T: int, policy, overhead: bool,
         # partial sum + AllReduce reassociates the floats and flips
         # low bits of e.g. Eq. 16's gap_sum). Identity when unsharded.
         repl_pin = repl if mesh is not None else (lambda x: x)
+
+        def _write_pushes(buf, agg_carry, count, kfin, fin, users, pulled,
+                          corun, t, version):
+            # compact the finishers to the front in user order: a stable
+            # sort on "not finished" carries each row's inputs along as
+            # payloads, so no per-user gather or scatter over n follows
+            _, users, pulled, corun = lax.sort(
+                (jnp.logical_not(fin).astype(jnp.int8), users, pulled,
+                 corun), num_keys=1, is_stable=True)
+
+            def block(j, c):
+                buf, agg_carry = c
+                # the last window slides back inside the n_arr sorted
+                # users; the rows it repeats are the same values again
+                lo = jnp.minimum(j * K, n_arr - K)
+                u, pa, co = (lax.dynamic_slice(x, (lo,), (K,))
+                             for x in (users, pulled, corun))
+                rank = lo + jnp.arange(K)
+                if policy.sync_rounds:
+                    lag = version - pa
+                    vn = _jax_trace_v_norm(v_norm0, version, jnp, fp_zero)
+                else:
+                    vers = version + rank
+                    lag = vers - pa
+                    vn = _jax_trace_v_norm(v_norm0, vers, jnp, fp_zero)
+                gap = _jax_gradient_gap(vn, lag, eta, beta)
+                if policy.sync_rounds:
+                    # FedAvg rounds average; no per-push weight
+                    w = jnp.ones((K,), f)
+                else:
+                    pv = SimpleNamespace(
+                        jnp=jnp, lax=lax, jax=jax, float_dtype=f,
+                        lag=lag, gap=gap, v_norm=vn, users=u,
+                        consts=agg_ops)
+                    agg_carry, w = agg.scan_weight(agg_carry, pv)
+                    w = jnp.broadcast_to(w, (K,))
+                rows = jnp.stack(
+                    [jnp.broadcast_to(t, (K,)).astype(f), u.astype(f),
+                     lag.astype(f), gap.astype(f), co.astype(f),
+                     w.astype(f)], axis=1)
+                # rows ranked past kfin land past the new count, where
+                # the next pushing slot (or nothing) overwrites them; a
+                # start past capacity clamps into the K slack rows
+                return lax.dynamic_update_slice(buf, rows, (count + lo, 0)), \
+                    agg_carry
+
+            return lax.fori_loop(0, (kfin + K - 1) // K, block,
+                                 (buf, agg_carry))
 
         def step(s, xs):
             srow, crow, t = xs
@@ -779,17 +845,19 @@ def _build_jax_chunk_fn(n: int, chunk: int, T: int, policy, overhead: bool,
                 in_flight = in_flight - kfin
                 corun_updates = s.corun_updates + jnp.sum(fin & corun)
 
-            # push events: scatter one fixed-width row per finisher at the
-            # buffer cursor (user-index order within the slot, the loop
-            # oracle's push order); rows past capacity drop, count stays
-            # exact so the driver can detect overflow and retry
+            # push events: one fixed-width row per finisher at the buffer
+            # cursor, in user-index order within the slot (the loop
+            # oracle's push order), written as ceil(kfin / K) contiguous
+            # blocks of K rows — and nothing at all in a slot where nobody
+            # finishes. count stays exact so the host loop can detect
+            # overflow and retry
             events = s.events
             agg_carry = s.agg_carry
             if collect:
                 with jax.named_scope("slot.push_log"):
-                    # the scatter runs REPLICATED under a mesh (pads never
-                    # finish, so the cumsum ranks and the buffer cursor match
-                    # the unsharded scan; the buffer itself is a replicated
+                    # the phase runs REPLICATED under a mesh (pads never
+                    # finish, so the ranks and the buffer cursor match the
+                    # unsharded scan; the buffer itself is a replicated
                     # carry leaf) — cheap, since only (n,) vectors and the
                     # O(capacity) buffer are involved, never the big state
                     if mesh is None:
@@ -798,39 +866,15 @@ def _build_jax_chunk_fn(n: int, chunk: int, T: int, policy, overhead: bool,
                     else:
                         fin_e, corun_e, pulled_e, ar_e = \
                             repl(fin), repl(corun), repl(pulled_at), repl(ar)
-                    rank = jnp.cumsum(fin_e) - fin_e
-                    if policy.sync_rounds:
-                        lag = version - pulled_e
-                        vn = _jax_trace_v_norm(v_norm0, version, jnp, fp_zero)
-                    else:
-                        vers = version + rank
-                        lag = vers - pulled_e
-                        vn = _jax_trace_v_norm(v_norm0, vers, jnp, fp_zero)
-                    gap = _jax_gradient_gap(vn, lag, eta, beta)
-                    if policy.sync_rounds:
-                        # FedAvg rounds average; no per-push weight
-                        w = jnp.ones((n_arr,), f)
-                    else:
-                        pv = SimpleNamespace(
-                            jnp=jnp, lax=lax, jax=jax, float_dtype=f,
-                            lag=lag, gap=gap, v_norm=vn, users=ar_e,
-                            consts=agg_ops)
-                        if mesh is not None:
-                            agg_carry = jax.tree.map(repl, agg_carry)
-                        agg_carry, w = agg.scan_weight(agg_carry, pv)
-                        if mesh is not None:
-                            agg_carry = jax.tree.map(place, agg_carry)
-                        w = jnp.broadcast_to(w, (n_arr,))
-                    rows = jnp.stack(
-                        [jnp.broadcast_to(t, (n_arr,)).astype(f),
-                         ar_e.astype(f),
-                         lag.astype(f), gap.astype(f), corun_e.astype(f),
-                         w.astype(f)],
-                        axis=1)
-                    pos = jnp.where(fin_e, events.count + rank, capacity)
-                    events = PushBuffer(
-                        events.rows.at[pos].set(rows, mode="drop"),
-                        events.count + kfin)
+                        agg_carry = jax.tree.map(repl, agg_carry)
+                    buf, agg_carry = lax.cond(
+                        kfin > 0, _write_pushes,
+                        lambda buf, agg_carry, *_: (buf, agg_carry),
+                        events.rows, agg_carry, events.count, kfin, fin_e,
+                        ar_e, pulled_e, corun_e, t, version)
+                    if mesh is not None:
+                        agg_carry = jax.tree.map(place, agg_carry)
+                    events = PushBuffer(buf, events.count + kfin)
 
             with jax.named_scope("slot.train"):
                 if policy.sync_rounds:
@@ -1159,9 +1203,12 @@ def _run_jax(sim) -> SimResult:
             rs = _pad_setup(rs, n_arr, sim)
     n, T, chunk, collect, f, i = rs.n, rs.T, rs.chunk, rs.collect, rs.f, rs.i
     cap = rs.cap
+    K = _push_block(n_arr or n)
 
     def fresh_events(c):
-        ev = PushBuffer(jnp.zeros((c, 6), f), jnp.asarray(0, i))
+        # K slack rows: a push block that starts at or past capacity
+        # lands there, never on a row the drain reads
+        ev = PushBuffer(jnp.zeros((c + K, 6), f), jnp.asarray(0, i))
         if mesh is not None:    # the buffer is a replicated carry leaf
             ev = PushBuffer(jax.device_put(ev.rows, rs.repl_sharding),
                             jax.device_put(ev.count, rs.repl_sharding))
@@ -1205,9 +1252,11 @@ def _run_jax(sim) -> SimResult:
                 with TraceAnnotation("scan.overflow", cap=cap):
                     state = prev.replace(events=fresh_events(cap))
                 continue
-            with TraceAnnotation("scan.drain", pushes=cnt):
-                if cnt:
-                    log.extend_rows(np.asarray(state.events.rows[:cnt]))
+            with TraceAnnotation("scan.drain", pushes=cnt) as span:
+                rows = np.asarray(state.events.rows[:cnt]) if cnt \
+                    else np.zeros((0, 6))
+                log.extend_rows(rows)
+                span.set_metadata(**_drain_counts([rows[:, 0]], K))
                 cnt0 = jnp.asarray(0, i)
                 if mesh is not None:
                     cnt0 = jax.device_put(cnt0, rs.repl_sharding)
@@ -1316,7 +1365,7 @@ def run_jax_sweep(sims) -> List[SimResult]:
     ``SimResult`` (traces, push log, final host state) identical — bit
     for bit on discrete outputs, to float-sum reordering on energies —
     to its per-point ``_run_jax`` run. Push buffers are batched
-    ``(B, cap, 6)``; if ANY config overflows a chunk, the chunk re-runs
+    ``(B, cap + K, 6)``; if ANY config overflows a chunk, the chunk re-runs
     from its saved entry state with the buffer doubled for every row
     (per-config counts stay exact)."""
     import jax
@@ -1365,12 +1414,13 @@ def _run_jax_batch(sims, jax, jnp) -> List[SimResult]:
     n, T, chunk, collect, f, i = p0.n, p0.T, p0.chunk, p0.collect, \
         p0.f, p0.i
     cap = p0.cap
+    K = _push_block(n)
     with TraceAnnotation("scan.to_device"):
         rs = _ops_to_device(rs, jax, jnp)
         state = rs.state
         if collect:
             state = state.replace(events=PushBuffer(
-                jnp.zeros((B, cap, 6), f), jnp.zeros((B,), i)))
+                jnp.zeros((B, cap + K, 6), f), jnp.zeros((B,), i)))
     tables, app_sched, app_choice = rs.tables, rs.app_sched, rs.app_choice
     scalars, pol_ops, agg_ops, dyn_ops = \
         rs.scalars, rs.pol_ops, rs.agg_ops, rs.dyn_ops
@@ -1400,13 +1450,16 @@ def _run_jax_batch(sims, jax, jnp) -> List[SimResult]:
                 cap = _next_pow2(int(counts.max()))
                 with TraceAnnotation("scan.overflow", cap=cap):
                     state = prev.replace(events=PushBuffer(
-                        jnp.zeros((B, cap, 6), f), jnp.zeros((B,), i)))
+                        jnp.zeros((B, cap + K, 6), f), jnp.zeros((B,), i)))
                 continue
-            with TraceAnnotation("scan.drain", pushes=int(counts.sum())):
+            with TraceAnnotation("scan.drain",
+                                 pushes=int(counts.sum())) as span:
                 rows = np.asarray(state.events.rows)
-                for b in range(B):
-                    if counts[b]:
-                        logs[b].extend_rows(rows[b, :counts[b]])
+                drained = [rows[b, :counts[b]] for b in range(B)]
+                for lg, r in zip(logs, drained):
+                    lg.extend_rows(r)
+                span.set_metadata(**_drain_counts(
+                    [r[:, 0] for r in drained], K))
                 state = state.replace(events=PushBuffer(
                     state.events.rows, jnp.zeros((B,), i)))
         with TraceAnnotation("scan.traces"):

@@ -12,6 +12,7 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.core import Scenario
@@ -97,6 +98,31 @@ def test_a_run_nests_its_spans_and_counts_its_pushes(tmp_path):
              ("sim.reset", "scan.setup", "scan.to_device", "scan.chunk")]
     assert order == sorted(order)
     assert named(events, "scan.finish")[0][1] > chunks[-1][2]
+
+
+def slots_and_blocks(t, K):
+    """Pushing slots and ``K``-row blocks, recounted from a ``t`` column."""
+    _, k = np.unique(t, return_counts=True)
+    return len(k), int(sum(-(-k // K)))
+
+
+@pytest.mark.parametrize("sweep", [False, True], ids=["run", "sweep"])
+def test_the_drain_counts_the_slots_and_blocks_it_wrote(tmp_path, sweep):
+    scs = [fleet(seed=s, app_arrival_p=0.05) for s in (1, 2, 3)]
+    if sweep:
+        results, events = traced(tmp_path, lambda: run_sweep(scs))
+    else:
+        sim = scs[0].build()
+        results, events = traced(tmp_path, lambda: [sim.run()])
+    drains = [d[3] for d in named(events, "scan.drain")]
+    assert len(drains) == 2
+    K = ve._push_block(64)
+    for c, args in enumerate(drains):       # chunks of 320 slots
+        want = [slots_and_blocks(t[(t >= 320 * c) & (t < 320 * (c + 1))], K)
+                for t in (r.push_log.arrays()[0] for r in results)]
+        assert (args["push_slots"], args["blocks"]) == \
+            tuple(map(sum, zip(*want)))
+        assert args["push_slots"] > 0
 
 
 def test_an_overflowing_chunk_is_marked_with_its_new_capacity(tmp_path):
