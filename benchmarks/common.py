@@ -31,9 +31,9 @@ def write_json(rows: Iterable[dict], path: str,
                meta: Optional[dict] = None) -> str:
     """Persist bench rows as a machine-readable artifact.
 
-    The perf trajectory across PRs is diffed from these files (e.g.
-    ``BENCH_sim_scale.json``), so the schema stays flat: a ``meta`` header
-    (timestamp, host) plus the same row dicts the CSV stream carries."""
+    The schema stays flat (e.g. ``BENCH_fig4_tradeoff.json``): a ``meta``
+    header (timestamp, host) plus the same row dicts the CSV stream
+    carries."""
     doc = {
         "meta": {
             "generated_unix": int(time.time()),

@@ -1,4 +1,8 @@
-"""Benchmark driver: one module per paper table/figure + system benches.
+"""Paper results driver: one module per table or figure of the paper.
+
+They reproduce the paper's energy, Q, H and accuracy results and Table
+III's per-decision overhead. The system's speed is measured only by
+``bench/`` on the chip.
 
     PYTHONPATH=src python -m benchmarks.run            # fast settings
     PYTHONPATH=src python -m benchmarks.run --full     # paper horizons
@@ -11,10 +15,8 @@ import sys
 import time
 
 from benchmarks import (bench_fig4_tradeoff, bench_fig5_convergence,
-                        bench_fig6_arrival, bench_kernels,
-                        bench_real_scale, bench_roofline,
-                        bench_serve_ingest, bench_sim_scale,
-                        bench_table2_energy, bench_table3_overhead)
+                        bench_fig6_arrival, bench_table2_energy,
+                        bench_table3_overhead)
 from benchmarks.common import emit
 from repro.launch.cache import enable_compile_cache
 
@@ -24,11 +26,6 @@ BENCHES = [
     ("fig4", bench_fig4_tradeoff),
     ("fig6", bench_fig6_arrival),
     ("fig5", bench_fig5_convergence),
-    ("sim_scale", bench_sim_scale),
-    ("real_scale", bench_real_scale),
-    ("kernels", bench_kernels),
-    ("roofline", bench_roofline),
-    ("serve_ingest", bench_serve_ingest),
 ]
 
 

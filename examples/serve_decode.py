@@ -1,6 +1,5 @@
 """Batched serving: prefill + greedy decode with a KV/SSM cache for any
-assigned architecture (smoke size on CPU; the same steps lower on the
-production mesh via launch.dryrun).
+assigned architecture (smoke size on CPU).
 
     PYTHONPATH=src python examples/serve_decode.py --arch mamba2-370m
 """

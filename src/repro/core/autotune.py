@@ -83,8 +83,7 @@ def estimate_device_bytes(n: int, T: int, chunk: int, capacity: int,
     """Modeled peak per-device bytes of a sharded run: resident state
     rows + whole-horizon arrival columns for this device's shard, the
     in-flight ``(chunk, rows)`` arrival slice, and the replicated push
-    buffer. Reported as ``mem_per_device_mb`` in ``bench_sim_scale`` so
-    CPU-host numbers transfer to accelerator meshes by arithmetic."""
+    buffer."""
     rows = -(-int(n) // max(int(n_devices), 1))
     per_user = _STATE_BYTES_PER_USER if dyn_active else 19 * 8
     per_slot = rows * _ARRIVAL_BYTES_PER_SLOT

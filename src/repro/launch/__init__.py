@@ -1,2 +1,2 @@
-"""Distribution/launch layer: production meshes, sharded train/serve steps,
-the multi-pod dry-run driver, and the roofline analyser."""
+"""Distribution/launch layer: device meshes, the compile cache, and the LM
+islands and serving drivers with their step functions."""

@@ -1,7 +1,8 @@
 """JAX's persistent compilation cache, kept at one stable place.
 
-Every entry point (``chip_smoke.py``, ``benchmarks/run.py``, the examples
-through ``examples/_bootstrap.py``, ``launch/train.py``) calls
+Every entry point (the chip benchmark through ``bench/harness.py``,
+``chip_smoke.py``, ``benchmarks/run.py``, the examples through
+``examples/_bootstrap.py``, ``launch/train.py``) calls
 :func:`enable_compile_cache` before its first compile. The directory is
 ``$JAX_COMPILATION_CACHE_DIR`` when that is set, and otherwise
 ``<checkout>/.jax_cache`` (git-ignored). The path is part of the cache's

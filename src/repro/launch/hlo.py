@@ -1,4 +1,4 @@
-"""HLO-text analysis: collective-bytes accounting for the roofline.
+"""HLO-text analysis: collective-bytes accounting.
 
 Parses ``compiled.as_text()`` (post-SPMD, per-device module) and sums the
 bytes of every collective op:
@@ -8,7 +8,7 @@ bytes of every collective op:
 Bytes = the op's OUTPUT shape bytes (operand bytes for these ops equal the
 output except all-gather, where the output is the gathered extent — the
 amount that actually crosses links per device; ring-algorithm per-link
-traffic factors are applied later in roofline.py).
+traffic factors are not applied).
 
 While-loop bodies (lax.scan over layers / microbatches) appear ONCE in the
 text but execute trip-count times; ``collective_bytes`` walks the
@@ -239,9 +239,9 @@ def quadratic_traffic(hlo: str, min_dim: int = 2048,
     SSD intra-chunk (..., Q, Q, nh) masks). rank_min excludes rank-2/3
     lookalikes (logits, MLP activations).
 
-    Used to model the Pallas kernel variants in the roofline: the kernels
-    keep these tiles in VMEM, so kernel_hbm = hbm_bytes -
-    quadratic_traffic (q/k/v/o and everything else unchanged)."""
+    Models the Pallas kernel variants, which keep these tiles in VMEM:
+    kernel_hbm = hbm_bytes - quadratic_traffic (q/k/v/o and everything
+    else unchanged)."""
     comps, entry = split_computations(hlo)
     lo = min_dim if second_min is None else second_min
 

@@ -1,13 +1,8 @@
-"""Production meshes.
-
-Single pod: 16 x 16 = 256 chips (TPU v5e pod), axes ("data", "model").
-Multi-pod:  2 x 16 x 16 = 512 chips, axes ("pod", "data", "model") — the
-"pod" axis is an extra pure-DP dimension over the slower inter-pod (DCN)
-links; within the paper's system each pod is one *island* whose updates the
-async parameter server applies (launch/train.py).
+"""Device meshes: the simulator's ``("users",)`` mesh, the serving tier's
+``("shard",)`` mesh, and ``("data", "model")`` meshes for the LM drivers.
 
 Functions, not module constants: importing this module never touches jax
-device state (the dry-run must set XLA_FLAGS before first jax init).
+device state (a caller may set XLA_FLAGS before first jax init).
 ``get_abstract_mesh`` is the one place model code reads the ambient mesh
 (set by ``jax.set_mesh``) from.
 """
@@ -32,12 +27,6 @@ def make_mesh(shape, axes):
     shape, axes = tuple(shape), tuple(axes)
     return jax.make_mesh(shape, axes,
                          axis_types=(AxisType.Auto,) * len(axes))
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes)
 
 
 def make_host_mesh(model: int = 1):

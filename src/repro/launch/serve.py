@@ -1,9 +1,8 @@
 """Batched serving driver: continuous-batching decode loop with KV cache.
 
 Prefill a batch of prompts, then greedy-decode with the jitted decode step.
-At production scale the same prefill/decode steps lower on the 16x16 mesh
-(dry-run shapes prefill_32k / decode_32k / long_500k); this driver runs the
-smoke configs end-to-end on the host and reports tokens/s.
+This driver runs the smoke configs end-to-end on the host and reports
+tokens/s.
 
     python -m repro.launch.serve --arch qwen3-0.6b --batch 4 --prompt-len 32 \
         --gen 32

@@ -9,7 +9,7 @@ Shapes (LM family, seq_len x global_batch):
                                   full-attention archs (DESIGN.md §6).
 
 ``input_specs`` returns weak-type-correct, shardable ShapeDtypeStructs — no
-device allocation; the dry-run lowers against them.
+device allocation.
 """
 from __future__ import annotations
 
@@ -47,11 +47,6 @@ TRAIN_MICROBATCHES = 8
 FSDP_ARCHS = {"qwen3-moe-30b-a3b", "internlm2-20b", "internvl2-76b",
               "granite-moe-1b-a400m", "phi4-mini-3.8b", "whisper-large-v3"}
 
-# >=20B archs whose bf16 weights + 32k KV cache cannot share one v5e chip
-# under TP-only sharding: serve with weight-sharded (FSDP-style) params too —
-# the per-layer all-gather amortizes over the 128-sequence decode batch.
-FSDP_SERVE_ARCHS = {"internvl2-76b", "internlm2-20b"}
-
 
 def applicable(cfg, shape: str) -> bool:
     """long_500k only for sub-quadratic (O(1)-state decode) archs."""
@@ -61,11 +56,11 @@ def applicable(cfg, shape: str) -> bool:
 
 
 def production_config(cfg, shape: str):
-    """Per-cell production overrides that make the cell fit HBM (recorded in
-    the dry-run JSON): chunked attention for 4k+ sequence work (einsum
-    attention materializes (Sq, Sk) scores — 100s of GB/device at 32k), and
-    sequence-parallel activations for the wide (d_model >= 3k) train cells
-    (the remat stack L x (B, S, D) dominates otherwise)."""
+    """Per-cell production overrides that make the cell fit HBM: chunked
+    attention for 4k+ sequence work (einsum attention materializes (Sq, Sk)
+    scores — 100s of GB/device at 32k), and sequence-parallel activations
+    for the wide (d_model >= 3k) train cells (the remat stack L x (B, S, D)
+    dominates otherwise)."""
     spec = SHAPES[shape]
     over = {}
     if cfg.num_heads and spec.kind in ("train", "prefill") \

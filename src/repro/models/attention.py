@@ -1,6 +1,6 @@
 """GQA attention with RoPE, qk-norm, optional bias, KV-cache decode.
 
-The default implementation is pure XLA (jnp einsums) so that dry-run
+The default implementation is pure XLA (jnp einsums) so that
 compilation on any backend succeeds; the Pallas flash kernel
 (`repro.kernels.flash_attention`) is swapped in via cfg.attention_impl="flash"
 on real TPU hardware.
@@ -80,8 +80,7 @@ def causal_mask(Sq: int, Sk: int, offset: int = 0):
 def _sdpa_chunked(q, k, v, cfg, *, causal: bool = True, offset: int = 0):
     """Blocked attention: lax.scan over q-row blocks, scores for one block at
     a time — peak scores memory (B,KV,G,bq,Sk) instead of (...,Sq,Sk). The
-    XLA stand-in for the Pallas flash kernel at 32k+ sequence (and its
-    sharding/collective twin in the dry-run).
+    XLA stand-in for the Pallas flash kernel at 32k+ sequence.
 
     q: (B,Sq,H,hd); k/v: (B,Sk,KV,hd)."""
     B, Sq, H, hd = q.shape

@@ -75,10 +75,10 @@ class ModelConfig:
     # --- training-step options ---------------------------------------------------
     remat: str = "full"           # none | full  (activation checkpoint per layer)
     logits_softcap: float = 0.0
-    scan_layers: bool = True      # False => python-unrolled stacks. Used by the
-                                  # dry-run flop calibration (XLA CPU cost
-                                  # analysis counts while bodies once) and by
-                                  # hillclimb experiments; semantics identical.
+    scan_layers: bool = True      # False => python-unrolled stacks, whose
+                                  # XLA CPU cost analysis counts every layer
+                                  # (it counts while bodies once); semantics
+                                  # identical.
     parallel_layout: str = "tp"   # tp: weights sharded over "model" (the
                                   # default); dp: weights replicated and the
                                   # batch sharded over EVERY mesh axis — the

@@ -94,8 +94,8 @@ def _shard_seq(x, cfg):
        the partitioner may pick different strategies for different depths
        (observed: a 1-layer unrolled variant costing MORE per device than a
        2-layer one) and insert resharding all-gather/permute churn between
-       layers. Pinned boundaries make per-layer cost uniform — which the
-       dry-run's depth-extrapolation relies on.
+       layers. Pinned boundaries make per-layer cost uniform, so cost
+       extrapolates linearly in depth.
     2. Sequence parallelism (cfg.shard_activations): put S on "model"
        between layers — norms are elementwise over D so SP is free, the
        remat stack shrinks by the TP degree, and GSPMD gathers S only in
@@ -125,9 +125,8 @@ def _scan(body, init, xs, cfg):
 
     The unrolled path consumes the SAME stacked params (slicing the leading
     layer dim) so shardings/init are identical; it exists because XLA's CPU
-    cost analysis counts while-loop bodies once — the dry-run lowers small
-    unrolled variants to calibrate exact per-layer flop/byte/collective
-    counts (launch/dryrun.py)."""
+    cost analysis counts while-loop bodies once, so only an unrolled stack
+    gives exact per-layer flop/byte/collective counts."""
     if cfg.scan_layers:
         return jax.lax.scan(body, init, xs)
     n = jax.tree.leaves(xs)[0].shape[0]

@@ -137,7 +137,7 @@ def moe_sorted(x, p, cfg):
 
 def _pin_rows(x3):
     """Anchor the dispatch-batch dim onto the dp axes before the vmapped
-    sort/gather chain: in python-unrolled graphs (dry-run calibration)
+    sort/gather chain: in python-unrolled graphs (scan_layers=False)
     GSPMD otherwise replicates some layers' (rows, E, C, D) buffers."""
     from repro.launch.mesh import get_abstract_mesh
     mesh = get_abstract_mesh()
@@ -184,8 +184,8 @@ def moe(x3, p, cfg):
             # scan (not vmap) over the seq chunks: one chunk's (E, C, D)
             # dispatch buffers live at a time — 8x less prefill memory at
             # 32k; chunks would serialize through the MXU anyway.
-            # (unrolled when cfg.scan_layers=False so the dry-run's flop
-            # calibration counts every chunk — see models.model._scan)
+            # (unrolled when cfg.scan_layers=False so XLA's cost analysis
+            # counts every chunk — see models.model._scan)
             chunks = jnp.moveaxis(x3.reshape(B, S // C0, C0, D), 1, 0)
 
             if cfg.scan_layers:

@@ -1,6 +1,6 @@
-"""Shared test configuration. NOTE: no XLA_FLAGS here — smoke tests and
-benches must see the host's real (single) device; only launch/dryrun.py
-sets the 512-placeholder-device flag, in its own process."""
+"""Shared test configuration. NOTE: no XLA_FLAGS here — smoke tests must
+see the host's real devices; a multi-device run sets XLA_FLAGS in its own
+environment (CI's sharded-sim job)."""
 import os
 import sys
 

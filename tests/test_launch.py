@@ -1,4 +1,4 @@
-"""Launch layer: shapes/specs, lowering on an abstract production mesh,
+"""Launch layer: shapes/specs, step functions on a small host mesh,
 train and serve drivers end-to-end (small scale)."""
 import dataclasses
 import os
@@ -10,7 +10,6 @@ import pytest
 
 from repro.configs import get_config, get_smoke_config
 from repro.launch import shapes as shp
-from repro.launch.flops import model_flops
 from repro.launch.mesh import make_host_mesh, make_mesh
 
 
@@ -23,7 +22,7 @@ class TestShapes:
             assert not shp.applicable(get_config(arch), "long_500k"), arch
 
     def test_cell_count_is_32(self):
-        """10 archs x 4 shapes - 8 long_500k skips = 32 dry-run cells."""
+        """10 archs x 4 shapes - 8 long_500k skips = 32 cells."""
         from repro.configs import ARCHS
         n = sum(1 for a in ARCHS for s in shp.SHAPES
                 if shp.applicable(get_config(a), s))
@@ -59,25 +58,6 @@ class TestShapes:
         cfg, over = shp.production_config(get_config("mamba2-370m"),
                                           "train_4k")
         assert over == {}   # attention-free: nothing to override
-
-
-class TestModelFlops:
-    def test_train_6nd(self):
-        cfg = get_config("qwen3-0.6b")
-        f = model_flops(cfg, "train_4k")
-        assert f == pytest.approx(6 * cfg.param_count() * 256 * 4096)
-
-    def test_moe_uses_active(self):
-        cfg = get_config("qwen3-moe-30b-a3b")
-        f = model_flops(cfg, "train_4k")
-        assert f == pytest.approx(
-            6 * cfg.active_param_count() * 256 * 4096)
-        assert cfg.active_param_count() < 0.2 * cfg.param_count()
-
-    def test_decode_per_token(self):
-        cfg = get_config("qwen3-0.6b")
-        assert model_flops(cfg, "decode_32k") == pytest.approx(
-            2 * cfg.param_count() * 128)
 
 
 class TestLowerSmallMesh:

@@ -158,8 +158,7 @@ def test_moe_sorted_equals_dense_dispatch():
 
 
 def test_scan_equals_unrolled():
-    """cfg.scan_layers=False is semantically identical (dry-run calibration
-    correctness precondition)."""
+    """cfg.scan_layers=False is semantically identical."""
     for arch in ("qwen3_0_6b", "mamba2_370m", "zamba2_2_7b",
                  "whisper_large_v3"):
         cfg = get_smoke_config(arch)
