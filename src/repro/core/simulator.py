@@ -381,7 +381,7 @@ class FederatedSim:
             self.ml = ml_hooks or {}
         self.fleet = resolve_fleet(fleet if fleet is not None else "paper")
         self.fleet_spec = self.fleet.build(self.rng, cfg.n_users)
-        self.users = [UserState(device=d) for d in self.fleet_spec.devices]
+        self._users = None      # the loop engine's view, built on access
         self.sched = OnlineScheduler(cfg.V, cfg.L_b, cfg.eta, cfg.beta,
                                      cfg.epsilon, cfg.t_d)
         self.state = EngineState.init(cfg.n_users, cfg, self.policy,
@@ -444,8 +444,21 @@ class FederatedSim:
             raise ValueError(
                 f"arrival process {self.arrivals.name!r} produced app "
                 f"choices outside [0, {len(APPS)})")
+        # int32 once, checked first so no wide value wraps into range:
+        # the dtype the jax scan reads, so no run converts them again
+        self.app_choice = self.app_choice.astype(np.int32)
 
     # ------------------------------------------------------------ state views
+    @property
+    def users(self) -> List[UserState]:
+        """One ``UserState`` per fleet device: the loop oracle's per-user
+        view (and ``decide_loop`` hooks'). Built on first access, so the
+        batched engines, which never read it, never pay for it."""
+        if self._users is None:
+            self._users = [UserState(device=d)
+                           for d in self.fleet_spec.devices]
+        return self._users
+
     # Scalar server state lives in self.state (the shared EngineState);
     # these properties keep the historical sim.version / sim.in_flight /
     # sim._round_open spelling for policy hooks and ML backends.
@@ -601,9 +614,10 @@ class FederatedSim:
                              slots=n_slots(self.cfg)):
             if getattr(self, "_ran", False):
                 # a run consumes the mutable EngineState / UserState
-                # objects; reallocate them so repeated run() calls
-                # (warmup-then-timed patterns) start fresh instead of
-                # continuing silently from the previous run's state. A
+                # objects; reallocate the one and drop the other (rebuilt
+                # on access) so repeated run() calls (warmup-then-timed
+                # patterns) start fresh instead of continuing silently
+                # from the previous run's state. A
                 # real-ML backend is reset too (core/realml.py), so the
                 # run repeats the first; bare ml_hooks dicts have no
                 # reset and carry their state over.
@@ -614,8 +628,7 @@ class FederatedSim:
                         self.cfg.n_users, self.cfg, self.policy,
                         agg=self.agg, fleet=self.fleet_spec,
                         dynamics=self.dynamics)
-                    self.users = [UserState(device=d)
-                                  for d in self.fleet_spec.devices]
+                    self._users = None
                     self.sched.Q = 0.0
                     self.sched.H = 0.0
             self._ran = True
