@@ -17,8 +17,8 @@ runs at the TOP of every slot on all three engines —
     One pytree of per-user ``(n,)`` arrays (availability chain state,
     battery level, network state, drop counters, plus any run-constant
     per-user parameter gathers — per-device-class values must be gathered
-    per user here, like ``hetero_aware``'s scale carry). ``None`` for the
-    inactive ``none`` dynamics.
+    per user here, like ``hetero_aware``'s scale carry) and 0-d run
+    counters. ``None`` for the inactive ``none`` dynamics.
 ``host_step(dyn, rng_key, mode, corun, t_d)``
     The host (numpy) transition, shared verbatim by the loop oracle and
     the numpy engine — ONE implementation, so loop/vectorized parity
@@ -62,6 +62,14 @@ up/down. The shared effect semantics every engine implements identically
   no training progress; app arrivals stay exogenous (the pre-sampled
   usage trace keeps its meaning and no rng stream shifts).
 
+Every run counts the churn it had (``DeviceDynamics.counters``, copied
+onto ``SimResult``): ``drops`` (mid-training down-edges), ``departures``
+(waiting users that left the queue), ``recoveries`` (up-edges) and
+``lost`` (trainings lost: the drops under ``"lose"``, 0 under
+``"resume"``). The transition counts departures and recoveries from the
+slot's pre-effect modes, so every engine reports the same numbers; the
+jax scan keeps them as 0-d leaves of its carry.
+
 ``none`` (the default) is INACTIVE: no state, no draws, no effect — runs
 are bit-identical to the pre-dynamics engines (the goldens pin this).
 """
@@ -72,13 +80,21 @@ from typing import Any, Dict, Tuple, Type
 
 import numpy as np
 
-from .engine_state import MODE_TRAIN
+from .engine_state import MODE_TRAIN, MODE_WAIT
 
 __all__ = ["DeviceDynamics", "DynEffects", "NoDynamics",
            "MarkovChurnDynamics", "register_dynamics",
            "registered_dynamics", "resolve_dynamics", "dynamics_support"]
 
 DROPOUT_RULES = ("lose", "resume")
+# the churn every engine reports (SimResult fields of these names): the
+# per-user drop counts summed, the 0-d run totals of the dyn state, and
+# the trainings lost (the drops under "lose")
+RUN_TOTALS = ("departures", "recoveries")
+COUNTERS = ("drops",) + RUN_TOTALS + ("lost",)
+# the battery's levels and rates, in MarkovChurnDynamics._battery_units order
+BATTERY_KNOBS = ("battery_capacity", "drain_train", "drain_corun",
+                 "charge_rate", "battery_min", "battery_resume")
 
 
 @dataclasses.dataclass
@@ -133,9 +149,10 @@ class DeviceDynamics:
     # ------------------------------------------------------------- state
     def init_state(self, n: int, cfg=None, fleet=None):
         """Per-run per-user state as ONE pytree of ``(n,)``-leading
-        arrays (``EngineState.dyn``); ``None`` for inactive dynamics.
-        Per-device-class parameters must be gathered per user HERE (the
-        scan reads only this carry plus ``scan_operands`` scalars)."""
+        arrays and 0-d run totals (``EngineState.dyn``); ``None`` for
+        inactive dynamics. Per-device-class parameters must be gathered
+        per user HERE (the scan reads only this carry plus
+        ``scan_operands`` scalars)."""
         return None
 
     def scan_operands(self, cfg) -> tuple:
@@ -188,9 +205,11 @@ class DeviceDynamics:
             "degrade to the numpy engines")
 
     # -------------------------------------------------------- accessors
-    def total_drops(self, dyn) -> int:
-        """Mid-training drops recorded in ``dyn`` (0 when untracked)."""
-        return 0
+    def counters(self, dyn) -> Dict[str, int]:
+        """Run totals of the churn recorded in ``dyn``: ``drops``,
+        ``departures``, ``recoveries``, ``lost`` (all 0 when
+        untracked)."""
+        return dict.fromkeys(COUNTERS, 0)
 
 
 # ---------------------------------------------------------------------------
@@ -272,22 +291,36 @@ def _dyn_draw(rng_key, n):
     return np.asarray(k2, dtype=np.uint32), np.asarray(u)
 
 
-def _per_user(value, n, fleet, what) -> np.ndarray:
+def _whole(x: float) -> bool:
+    return abs(x - round(x)) <= 1e-9 * max(1.0, abs(x))
+
+
+def _per_user(value, n, fleet, what, per_user=False) -> np.ndarray:
     """Broadcast a scalar to ``(n,)`` or gather a per-device-class
     vector (one entry per catalog row of the run's ``FleetSpec``) per
-    user — the ``hetero_aware`` carry pattern."""
+    user — the ``hetero_aware`` carry pattern. With ``per_user`` an
+    ``(n,)`` array is taken as one value per user; where ``n`` equals
+    the fleet's class count the two readings cannot be told apart and
+    the vector is refused."""
     v = np.asarray(value, dtype=np.float64)
     if v.ndim == 0:
         return np.full(n, float(v))
+    n_classes = None if fleet is None else len(fleet.tables.t_train)
+    if per_user and v.shape == (n,):
+        if n == n_classes:
+            raise ValueError(
+                f"{what} of shape ({n},) is ambiguous: this fleet has "
+                f"{n} users and {n} device classes")
+        return v.copy()
     if fleet is None:
         raise ValueError(
             f"per-device-class {what} needs the run's FleetSpec to "
             "gather per-user values; engines pass it automatically")
-    n_classes = len(fleet.tables.t_train)
     if v.shape != (n_classes,):
+        also = f" or a ({n},) per-user array" if per_user else ""
         raise ValueError(
             f"{what} must be a scalar or a ({n_classes},) per-device-"
-            f"class vector for this fleet, got shape {v.shape}")
+            f"class vector{also} for this fleet, got shape {v.shape}")
     return v[fleet.device_ids]
 
 
@@ -305,10 +338,23 @@ class MarkovChurnDynamics(DeviceDynamics):
     - **Battery**: drains while actually training (``drain_train``
       capacity-fractions/s; ``drain_corun`` while co-running — co-run
       training works the SoC harder) and charges otherwise
-      (``charge_rate``), clipped to ``[0, capacity]``. A user
-      participates only while ``battery > battery_min`` (DEAL-style
-      battery gating): the threshold is part of effective availability,
-      so a mid-training battery collapse IS a dropout.
+      (``charge_rate``), clipped to ``[0, capacity]``. The battery gates
+      participation (DEAL-style): a user whose level falls to
+      ``battery_min`` or below is held down, and is let up again only
+      once its level is above ``battery_resume`` (default
+      ``battery_min``: no hysteresis; a higher resume level keeps a user
+      at the threshold from going up and down every few slots). The gate
+      is part of effective availability, so a mid-training battery
+      collapse IS a dropout. ``battery_init`` is the starting charge as
+      a capacity fraction in [0, 1]: a scalar for the whole fleet, a
+      per-device-class vector, or a per-user ``(n,)`` array (a fleet
+      that starts at a spread of levels). With ``battery_quantum`` the
+      level is kept as a whole count of quanta (capacity units per
+      quantum): capacity, thresholds and every per-slot step must be
+      whole quanta, starting levels round to the nearest quantum, and
+      ``dyn["battery"]`` holds the count. Every level is then exact in
+      float32 as in float64, so a threshold crossing lands in the same
+      slot at either precision.
     - **Network**: a good/bad 2-state chain (``p_net_bad`` /
       ``p_net_recover``); in the bad state re-arrival — post-push AND
       post-recovery — costs ``net_delay_slots`` extra cooldown slots,
@@ -316,17 +362,19 @@ class MarkovChurnDynamics(DeviceDynamics):
 
     ``dropout`` picks the mid-training rule: ``"lose"`` (in-flight work
     lost) or ``"resume"`` (paused while down, ``resume_penalty_s`` extra
-    training seconds). ``drops`` counts mid-training down-edges either
-    way.
+    training seconds). The state counts, per user, ``drops``
+    (mid-training down-edges, either rule) and, as 0-d run totals,
+    ``departures`` and ``recoveries`` (``counters``).
     """
 
     name = "markov"
 
     def __init__(self, p_off=0.002, p_on=0.05, *,
                  battery_capacity: float = 1.0,
-                 battery_init: float = 1.0,
+                 battery_init=1.0,
                  drain_train: float = 2e-4, drain_corun: float = 3e-4,
                  charge_rate: float = 1e-4, battery_min: float = 0.0,
+                 battery_resume=None, battery_quantum=None,
                  p_net_bad: float = 0.0, p_net_recover: float = 0.1,
                  net_delay_slots: int = 20,
                  dropout: str = "lose", resume_penalty_s: float = 0.0):
@@ -341,13 +389,22 @@ class MarkovChurnDynamics(DeviceDynamics):
         if battery_capacity <= 0.0:
             raise ValueError(
                 f"battery_capacity must be positive, got {battery_capacity}")
-        if not 0.0 <= battery_init <= 1.0:
+        init = np.asarray(battery_init, dtype=float)
+        if init.ndim > 1 or init.size == 0 or \
+                not np.all((init >= 0.0) & (init <= 1.0)):
             raise ValueError(
-                f"battery_init is a capacity fraction in [0, 1], "
-                f"got {battery_init}")
+                "battery_init is a capacity fraction in [0, 1]: a scalar, "
+                "a per-device-class vector or a per-user (n,) array, got "
+                f"{battery_init if init.ndim == 0 else init.shape}")
         if not 0.0 <= battery_min < battery_capacity:
             raise ValueError(
                 f"battery_min must be in [0, capacity), got {battery_min}")
+        resume = battery_min if battery_resume is None \
+            else float(battery_resume)
+        if not battery_min <= resume < battery_capacity:
+            raise ValueError(
+                "battery_resume must be in [battery_min, capacity), got "
+                f"{battery_resume}")
         if min(drain_train, drain_corun, charge_rate) < 0.0:
             raise ValueError("drain/charge rates must be non-negative")
         if net_delay_slots < 0:
@@ -362,54 +419,109 @@ class MarkovChurnDynamics(DeviceDynamics):
         self.p_off = p_off
         self.p_on = p_on
         self.capacity = float(battery_capacity)
-        self.battery_init = float(battery_init)
+        self.battery_init = float(init) if init.ndim == 0 else init.copy()
         self.drain_train = float(drain_train)
         self.drain_corun = float(drain_corun)
         self.charge_rate = float(charge_rate)
         self.battery_min = float(battery_min)
+        self.battery_resume = resume
+        self.quantum = None
+        if battery_quantum is not None:
+            self.quantum = float(battery_quantum)
+            if not self.quantum > 0.0:
+                raise ValueError(
+                    f"battery_quantum must be positive, got {battery_quantum}")
+            for what, v in zip(BATTERY_KNOBS,
+                               self._battery_units(quantized=False)):
+                if not _whole(v / self.quantum):
+                    raise ValueError(
+                        f"{what} must be a whole number of battery_quantum "
+                        f"({self.quantum}), got {v}")
+            if self.capacity / self.quantum > 2 ** 24:
+                raise ValueError(
+                    "battery_capacity must be at most 2**24 quanta, the "
+                    "whole numbers float32 holds exactly")
         self.p_net_bad = float(p_net_bad)
         self.p_net_recover = float(p_net_recover)
         self.net_delay_slots = int(net_delay_slots)
         self.dropout = dropout
         self.resume_penalty_s = float(resume_penalty_s)
 
+    def _battery_units(self, quantized=True):
+        """``capacity, drain_train, drain_corun, charge_rate,
+        battery_min, battery_resume`` in the unit the state's levels are
+        kept in: capacity units, or whole quanta with ``battery_quantum``
+        (unless not ``quantized``)."""
+        levels = (self.capacity, self.drain_train, self.drain_corun,
+                  self.charge_rate, self.battery_min, self.battery_resume)
+        if not quantized or self.quantum is None:
+            return levels
+        return tuple(float(round(v / self.quantum)) for v in levels)
+
     # ------------------------------------------------------------- state
     def init_state(self, n, cfg=None, fleet=None):
+        level = _per_user(self.battery_init, n, fleet, "battery_init",
+                          per_user=True) * self.capacity
+        if self.quantum is not None:
+            if cfg is not None:
+                self._check_steps(cfg.t_d)
+            level = np.rint(level / self.quantum)
         return {
             "on": np.ones(n, dtype=bool),
             "up": np.ones(n, dtype=bool),
-            "battery": np.full(n, self.battery_init * self.capacity),
+            "battery": level,
+            "low": np.zeros(n, dtype=bool),
             "net_bad": np.zeros(n, dtype=bool),
             "drops": np.zeros(n, dtype=np.int64),
             # run-constant per-user parameter gathers (traced carry)
             "p_off": _per_user(self.p_off, n, fleet, "p_off"),
             "p_on": _per_user(self.p_on, n, fleet, "p_on"),
+            # run totals (0-d: replicated, never padded)
+            **{k: np.zeros((), np.int32) for k in RUN_TOTALS},
         }
 
+    def _check_steps(self, t_d):
+        """With a quantum, each per-slot battery step (rate x ``t_d``, the
+        product the transition forms) must be a whole number of quanta
+        in float32 and in float64 alike."""
+        for what, rate in zip(BATTERY_KNOBS[1:4], self._battery_units()[1:4]):
+            for f in (np.float32, np.float64):
+                step = f(rate) * f(t_d)
+                if step != np.rint(step) or float(step) != rate * t_d:
+                    raise ValueError(
+                        f"{what} x t_d ({t_d}) must be a whole number of "
+                        f"battery_quantum ({self.quantum}) in float32 and "
+                        f"float64, got {rate * t_d} quanta")
+
     def scan_operands(self, cfg):
-        return (self.capacity, self.drain_train, self.drain_corun,
-                self.charge_rate, self.battery_min, self.p_net_bad,
-                self.p_net_recover, self.net_delay_slots,
-                self.resume_penalty_s)
+        return (*self._battery_units(), self.p_net_bad, self.p_net_recover,
+                self.net_delay_slots, self.resume_penalty_s)
 
     def pad_state(self, k):
-        # permanently-up rows: full battery (> battery_min, validated),
+        # permanently-up rows: full battery (> battery_resume, validated),
         # p_off=0 keeps the availability chain on under the engine's
         # fill-1.0 padded draws (1.0 >= 0), the net chain never turns bad
         # (1.0 < p_net_bad is false), and `up` never edges — so pad users
-        # parked in MODE_OFF draw nothing and stay parked forever
+        # parked in MODE_OFF draw nothing and stay parked forever. The
+        # 0-d counters are not per-user: padding keeps the state's own.
         return {
             "on": np.ones(k, dtype=bool),
             "up": np.ones(k, dtype=bool),
-            "battery": np.full(k, self.capacity),
+            "battery": np.full(k, self._battery_units()[0]),
+            "low": np.zeros(k, dtype=bool),
             "net_bad": np.zeros(k, dtype=bool),
             "drops": np.zeros(k, dtype=np.int64),
             "p_off": np.zeros(k),
             "p_on": np.zeros(k),
+            **{c: np.zeros((), np.int32) for c in RUN_TOTALS},
         }
 
-    def total_drops(self, dyn) -> int:
-        return 0 if dyn is None else int(np.asarray(dyn["drops"]).sum())
+    def counters(self, dyn) -> Dict[str, int]:
+        if dyn is None:
+            return super().counters(dyn)
+        drops = int(np.asarray(dyn["drops"]).sum())
+        return {"drops": drops, **{c: int(dyn[c]) for c in RUN_TOTALS},
+                "lost": drops if self.dropout == "lose" else 0}
 
     # ----------------------------------------------------------- the step
     # host_step and _transition/scan_step MUST stay formula-identical:
@@ -419,10 +531,7 @@ class MarkovChurnDynamics(DeviceDynamics):
         rng_key, u = _dyn_draw(rng_key, len(dyn["battery"]))
         dyn, eff = self._transition(
             np, dyn, u[0], u[1], mode, corun, t_d,
-            self.capacity, self.drain_train, self.drain_corun,
-            self.charge_rate, self.battery_min, self.p_net_bad,
-            self.p_net_recover, self.net_delay_slots,
-            self.resume_penalty_s)
+            *self.scan_operands(None))
         return dyn, rng_key, eff
 
     def scan_step(self, dyn, dv):
@@ -434,25 +543,22 @@ class MarkovChurnDynamics(DeviceDynamics):
         # pinned on/never-bad (see pad_state)
         u = dv.pad_users(u, 1.0)
         dv.rng_key = k2
-        (capacity, drain_train, drain_corun, charge_rate, battery_min,
-         p_net_bad, p_net_recover, net_delay_slots,
-         resume_penalty_s) = dv.consts
         return self._transition(
-            jnp, dyn, u[0], u[1], dv.mode, dv.corun, dv.t_d,
-            capacity, drain_train, drain_corun, charge_rate, battery_min,
-            p_net_bad, p_net_recover, net_delay_slots, resume_penalty_s,
+            jnp, dyn, u[0], u[1], dv.mode, dv.corun, dv.t_d, *dv.consts,
             zero=dv.fp_zero)
 
     @staticmethod
     def _transition(xp, dyn, u_avail, u_net, mode, corun, t_d,
                     capacity, drain_train, drain_corun, charge_rate,
-                    battery_min, p_net_bad, p_net_recover,
+                    battery_min, battery_resume, p_net_bad, p_net_recover,
                     net_delay_slots, resume_penalty_s, zero=0.0):
         """One slot, numpy or jnp (``xp``): elementwise only, identical
         operation order on both — bitwise parity under x64. ``zero`` is
         a traced 0.0 on the jax path: it forces the delta*t_d product to
         round before the battery add, which XLA's fma contraction would
-        otherwise skip (see policies._jax_trace_v_norm)."""
+        otherwise skip (see policies._jax_trace_v_norm). ``mode`` is the
+        slot's mode before the engines apply the effects, which the run
+        counters read."""
         up_prev = dyn["up"]
         training = mode == MODE_TRAIN
         # battery: drain while ACTUALLY training (a paused trainer is
@@ -470,14 +576,22 @@ class MarkovChurnDynamics(DeviceDynamics):
                       u_avail < dyn["p_on"])
         net_bad = xp.where(dyn["net_bad"], u_net >= p_net_recover,
                            u_net < p_net_bad)
-        # effective availability: chain on AND battery above threshold
-        up = on & (battery > battery_min)
+        # the battery gate: held down at or below battery_min, let up
+        # again above battery_resume
+        low = xp.where(dyn["low"], battery <= battery_resume,
+                       battery <= battery_min)
+        # effective availability: chain on AND battery not held down
+        up = on & ~low
         went_down = up_prev & ~up
         went_up = ~up_prev & up
         drops = dyn["drops"] + (went_down & training)
         net_extra = xp.where(net_bad, net_delay_slots, 0)
-        dyn2 = {"on": on, "up": up, "battery": battery, "net_bad": net_bad,
-                "drops": drops, "p_off": dyn["p_off"], "p_on": dyn["p_on"]}
+        dyn2 = {"on": on, "up": up, "battery": battery, "low": low,
+                "net_bad": net_bad, "drops": drops, "p_off": dyn["p_off"],
+                "p_on": dyn["p_on"],
+                "departures": dyn["departures"]
+                + xp.sum(went_down & (mode == MODE_WAIT)),
+                "recoveries": dyn["recoveries"] + xp.sum(went_up)}
         return dyn2, DynEffects(up=up, went_down=went_down,
                                 went_up=went_up, net_extra=net_extra,
                                 resume_penalty=resume_penalty_s)

@@ -109,7 +109,10 @@ class SimConfig:
     # Device dynamics (core/dynamics.py): availability / battery / network
     # churn as per-user state machines. Registry name or DeviceDynamics
     # instance; "none" (the paper's always-on fleet) is bit-identical to
-    # the pre-dynamics engines.
+    # the pre-dynamics engines. MarkovChurnDynamics takes battery_init as
+    # a scalar, a per-device-class vector or a per-user (n,) array of
+    # capacity fractions; every engine counts the run's churn (drops,
+    # departures, recoveries, lost) onto SimResult.
     dynamics: Union[str, DeviceDynamics] = "none"
 
     def __post_init__(self):
@@ -307,8 +310,13 @@ class SimResult:
     mean_Q: float
     mean_H: float
     corun_fraction: float
-    drops: int = 0                  # mid-training dropouts (device churn;
-    #                                 0 with dynamics="none")
+    # device churn (core/dynamics.py ``COUNTERS``; all 0 with
+    # dynamics="none"): mid-training down-edges, waiting users that left
+    # the queue, up-edges, and trainings lost under the "lose" rule
+    drops: int = 0
+    departures: int = 0
+    recoveries: int = 0
+    lost: int = 0
     placement: Optional[dict] = None  # jax scan: leaf name -> (device ids,
     #                                 shard shape) of the final carry
 
@@ -382,6 +390,8 @@ class FederatedSim:
         self.fleet = resolve_fleet(fleet if fleet is not None else "paper")
         self.fleet_spec = self.fleet.build(self.rng, cfg.n_users)
         self._users = None      # the loop engine's view, built on access
+        # the jax engine's last dispatched chunk (vector_engine.ScanChunk)
+        self.scan_chunk = None
         self.sched = OnlineScheduler(cfg.V, cfg.L_b, cfg.eta, cfg.beta,
                                      cfg.epsilon, cfg.t_d)
         self.state = EngineState.init(cfg.n_users, cfg, self.policy,
@@ -778,4 +788,4 @@ class FederatedSim:
             mean_Q=es.sum_Q / T if T else 0.0,
             mean_H=es.sum_H / T if T else 0.0,
             corun_fraction=es.corun_updates / max(updates, 1),
-            drops=dynamics.total_drops(es.dyn))
+            **dynamics.counters(es.dyn))

@@ -87,6 +87,7 @@ per-device memory budget (core/autotune.py).
 """
 from __future__ import annotations
 
+import dataclasses
 import logging
 from types import SimpleNamespace
 from typing import List, Tuple
@@ -104,7 +105,7 @@ from .simulator import SimResult, n_slots, trace_v_norm
 from .staleness import gradient_gap
 
 __all__ = ["run_vectorized", "run_jax_sweep", "sweep_bucket_key",
-           "jax_cache_stats", "reserve_jax_cache_capacity",
+           "jax_cache_stats", "reserve_jax_cache_capacity", "ScanChunk",
            "MODE_WAIT", "MODE_TRAIN", "MODE_COOL",
            "PLAN_HOLD", "PLAN_CORUN", "PLAN_SEP"]
 
@@ -417,7 +418,7 @@ class _NumpyEngine:
             mean_Q=s.sum_Q / T if T else 0.0,
             mean_H=s.sum_H / T if T else 0.0,
             corun_fraction=s.corun_updates / max(updates_total, 1),
-            drops=self.dynamics.total_drops(s.dyn))
+            **self.dynamics.counters(s.dyn))
 
 
 # ======================================================================
@@ -989,6 +990,33 @@ def _next_pow2(k: int) -> int:
     return c
 
 
+@dataclasses.dataclass(frozen=True)
+class ScanChunk:
+    """The scan chunk a jax run dispatched last (``sim.scan_chunk``): the
+    jitted chunk, the shapes, dtypes and shardings of the operands it ran
+    on (no device buffers), the chunk's length in slots and the push
+    buffer's capacity it was built for."""
+
+    fn: object
+    operands: tuple
+    chunk: int
+    cap: int
+
+    def hlo_text(self) -> str:
+        """The optimized HLO text of that chunk, compiled again for those
+        operands (the compile cache usually holds it)."""
+        return self.fn.lower(*self.operands).compile().as_text()
+
+
+def _abstract(tree, jax):
+    """Each array leaf of ``tree`` as a ``ShapeDtypeStruct``."""
+    return jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=getattr(x, "sharding", None),
+            weak_type=getattr(getattr(x, "aval", None), "weak_type",
+                              False)), tree)
+
+
 def _jax_run_setup(sim, jax, jnp, n_devices: int = 1):
     """HOST (numpy) operands + engine-dtype state for one sim, shared by
     the per-point path (`_run_jax`) and the batched sweep path
@@ -1234,10 +1262,10 @@ def _run_jax(sim) -> SimResult:
                                cap, rs.statics, agg, dynamics, mesh=mesh,
                                n_arr=n_arr)
             prev = state
-            state, (qs, hs, esum) = fn(
-                rs.tables, rs.app_sched, rs.app_choice, rs.scalars,
-                rs.pol_ops, rs.agg_ops, rs.dyn_ops, jnp.asarray(t0, i),
-                state)
+            operands = (rs.tables, rs.app_sched, rs.app_choice, rs.scalars,
+                        rs.pol_ops, rs.agg_ops, rs.dyn_ops,
+                        jnp.asarray(t0, i), state)
+            state, (qs, hs, esum) = fn(*operands)
         # the host waits here while the device runs the chunk
         with TraceAnnotation("scan.wait"):
             if collect:
@@ -1268,7 +1296,7 @@ def _run_jax(sim) -> SimResult:
             e_parts.append(np.asarray(esum, dtype=float)[:m])
         ci += 1
 
-    with TraceAnnotation("scan.finish"):
+    with TraceAnnotation("scan.finish") as span:
         # where the scan's per-user carry and arrival operands actually lived
         placement = {
             name: (tuple(sorted(d.id for d in x.sharding.device_set)),
@@ -1290,6 +1318,12 @@ def _run_jax(sim) -> SimResult:
         updates_total = int(np.sum(host.updates))
         sum_Q, sum_H = float(state.sum_Q), float(state.sum_H)
         corun_updates = int(state.corun_updates)
+        churn = dynamics.counters(host.dyn)
+        if dynamics.active:
+            span.set_metadata(**churn)
+        if rs.n_chunks:
+            sim.scan_chunk = ScanChunk(fn, _abstract(operands, jax), chunk,
+                                       cap)
         idx = np.arange(0, T, cfg.trace_every)
         if qs_parts:
             qs = np.concatenate(qs_parts)
@@ -1306,8 +1340,7 @@ def _run_jax(sim) -> SimResult:
             mean_Q=sum_Q / T if T else 0.0,
             mean_H=sum_H / T if T else 0.0,
             corun_fraction=corun_updates / max(updates_total, 1),
-            drops=dynamics.total_drops(sim.state.dyn),
-            placement=placement)
+            placement=placement, **churn)
 
 
 # ======================================================================
@@ -1493,5 +1526,5 @@ def _run_jax_batch(sims, jax, jnp) -> List[SimResult]:
                 push_log=logs[b], accuracy=[],
                 mean_Q=host.sum_Q / T, mean_H=host.sum_H / T,
                 corun_fraction=host.corun_updates / max(updates_total, 1),
-                drops=sim.dynamics.total_drops(host.dyn)))
+                **sim.dynamics.counters(host.dyn)))
         return results

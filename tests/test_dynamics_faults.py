@@ -337,3 +337,233 @@ class TestDynamicsConfigValidation:
         sim = Scenario(dynamics="none", **BASE).build()
         assert sim.state.dyn is None
         assert not sim.dynamics.active
+
+
+# ---------------------------------------------------------------------------
+# battery_init forms and the churn counters
+# ---------------------------------------------------------------------------
+# a regime where every rule fires: availability and network churn, and
+# batteries that cross battery_min both ways
+MIXED = dict(p_off=0.005, p_on=0.1, drain_train=1e-3, drain_corun=2e-3,
+             charge_rate=5e-4, battery_min=0.2, p_net_bad=0.05)
+
+
+def _outcome(sim, r):
+    """What must not differ between runs that should be the same: the
+    push keys, the per-user drops and battery levels, the counters."""
+    t, u, lag, _, corun, _ = r.push_log.arrays()
+    return ([np.asarray(a).tolist() for a in (t, u, lag, corun)],
+            np.asarray(sim.state.dyn["drops"]).tolist(),
+            np.asarray(sim.state.dyn["battery"], np.float64).tolist(),
+            (r.drops, r.departures, r.recoveries, r.lost))
+
+
+class TestBatteryInitForms:
+    N = BASE["n_users"]
+
+    def _run(self, engine, battery_init, dropout="lose"):
+        sim = Scenario(engine=engine, dynamics=MarkovChurnDynamics(
+            battery_init=battery_init, dropout=dropout, **MIXED),
+            **BASE).build()
+        return sim, sim.run()
+
+    @pytest.mark.parametrize("engine", ["loop", "vectorized", "jax"])
+    def test_scalar_class_vector_and_per_user_array_agree(self, engine):
+        """One level given three ways is one run."""
+        runs = [_outcome(*self._run(engine, b)) for b in
+                (0.5, [0.5] * 4, np.full(self.N, 0.5))]
+        assert runs[0] == runs[1] == runs[2]
+        assert runs[0][3][0] > 0 and runs[0][3][2] > 0
+
+    @pytest.mark.parametrize("dropout", ["lose", "resume"])
+    def test_per_user_levels_give_identical_runs_on_every_engine(
+            self, dropout):
+        levels = np.linspace(0.15, 0.95, self.N)
+        loop, vec, jx = (_outcome(*self._run(e, levels, dropout))
+                         for e in ("loop", "vectorized", "jax"))
+        assert loop == vec == jx
+        drops, departures, recoveries, lost = loop[3]
+        assert drops > 0 and recoveries > 0
+        assert lost == (drops if dropout == "lose" else 0)
+
+    def test_class_vector_gathers_by_device(self):
+        sim = Scenario(dynamics=MarkovChurnDynamics(
+            battery_init=[0.1, 0.2, 0.3, 0.4], battery_capacity=2.0),
+            **BASE).build()
+        want = 2.0 * np.array([0.1, 0.2, 0.3, 0.4])[
+            sim.fleet_spec.device_ids]
+        np.testing.assert_array_equal(sim.state.dyn["battery"], want)
+
+    # the parent's push keys, drops and final battery levels, from runs of
+    # the scalar form before battery_init took arrays (sha256 of the int64
+    # columns t, user, lag, corun, drops, then the float64 levels)
+    PINNED = {
+        "net_degraded": ("1ddfdb8bd15c8c62d593ff3f25d5b799b5bcfadf9808f9d"
+                         "37d30ad3606ac5a6c", 197, 3),
+        "mixed": ("758583561d006f84f4a8e3d1fec37bc883449a3a3bdd99fa7f4045"
+                  "a76af16c3b", 843, 6),
+    }
+
+    @pytest.mark.parametrize("engine", ["loop", "vectorized", "jax"])
+    @pytest.mark.parametrize("scenario", ["net_degraded", "mixed"])
+    def test_scalar_form_is_bit_identical_to_before(self, engine, scenario):
+        kw = SCENARIOS[scenario] if scenario != "mixed" \
+            else dict(MIXED, battery_init=0.5)
+        sim = Scenario(engine=engine, dynamics=MarkovChurnDynamics(
+            dropout="lose", resume_penalty_s=20.0, **kw), **BASE).build()
+        r = sim.run()
+        h = hashlib.sha256()
+        t, u, lag, _, corun, _ = r.push_log.arrays()
+        for a in (t, u, lag, corun, sim.state.dyn["drops"]):
+            h.update(np.ascontiguousarray(a, np.int64).tobytes())
+        h.update(np.asarray(sim.state.dyn["battery"], np.float64).tobytes())
+        assert (h.hexdigest(), r.drops, len(r.push_log)) == \
+            self.PINNED[scenario]
+
+    @pytest.mark.parametrize("bad, match", [
+        (1.5, "battery_init"), (-0.1, "battery_init"),
+        ([0.2, 1.2], "battery_init"), ([], "battery_init"),
+        ([[0.5, 0.5]], "battery_init"), ([0.5, float("nan")], "battery_init"),
+    ])
+    def test_bad_values_raise_at_construction(self, bad, match):
+        with pytest.raises(ValueError, match=match):
+            MarkovChurnDynamics(battery_init=bad)
+
+    @pytest.mark.parametrize("bad, match", [
+        ([0.5, 0.5], "per-user array"),       # neither 4 classes nor n
+        (np.full(BASE["n_users"] + 1, 0.5), "per-user array"),
+    ])
+    def test_bad_shapes_raise_at_build(self, bad, match):
+        with pytest.raises(ValueError, match=match):
+            Scenario(dynamics=MarkovChurnDynamics(battery_init=bad),
+                     **BASE).build()
+
+    def test_n_equal_to_the_class_count_is_ambiguous(self):
+        with pytest.raises(ValueError, match="ambiguous"):
+            Scenario(dynamics=MarkovChurnDynamics(
+                battery_init=[0.2, 0.4, 0.6, 0.8]),
+                **dict(BASE, n_users=4)).build()
+
+
+class TestChurnCounters:
+    @pytest.mark.parametrize("scenario", list(SCENARIOS))
+    def test_counters_agree_on_every_engine(self, scenario):
+        got = []
+        for engine in ("loop", "vectorized", "jax"):
+            r = _run(engine, _dyn(scenario))
+            got.append((r.drops, r.departures, r.recoveries, r.lost))
+        assert got[0] == got[1] == got[2]
+
+    def test_counters_count_what_they_name(self):
+        """Lost trainings are the drops under "lose" and none under
+        "resume"; a fleet that only waits loses nothing, and each of its
+        down-edges is a departure; no churn counts nothing."""
+        r = _run("vectorized", _dyn("mass_dropout"))
+        assert r.lost == r.drops > 0 and r.recoveries > 0
+        r = _run("vectorized", _dyn("mass_dropout", "resume"))
+        assert r.lost == 0 and r.drops > 0
+        r = _run("vectorized", _dyn("flapping"), app_arrival_p=0.0,
+                 policy="online", V=1e9)
+        assert r.updates == 0 and r.drops == r.lost == 0
+        assert r.departures > 0 and r.recoveries > 0
+        r = _run("vectorized", "none")
+        assert (r.drops, r.departures, r.recoveries, r.lost) == (0, 0, 0, 0)
+
+    def test_counters_are_0d_int32_leaves_of_the_state(self):
+        sim = Scenario(dynamics=_dyn("churn"), **BASE).build()
+        for name in ("departures", "recoveries"):
+            leaf = sim.state.dyn[name]
+            assert np.shape(leaf) == () and leaf.dtype == np.int32
+
+
+# ---------------------------------------------------------------------------
+# the battery gate: resume level (hysteresis) and whole-quantum levels
+# ---------------------------------------------------------------------------
+GATE = dict(MIXED, battery_min=0.15, battery_resume=0.2,
+            battery_quantum=1e-4)
+
+
+class TestBatteryGate:
+    N = BASE["n_users"]
+
+    def _run(self, engine, **kw):
+        sim = Scenario(engine=engine, dynamics=MarkovChurnDynamics(
+            battery_init=np.linspace(0.12, 0.3, self.N), **kw),
+            **BASE).build()
+        return sim, sim.run()
+
+    @pytest.mark.parametrize("engine", ["loop", "vectorized", "jax"])
+    def test_resume_at_battery_min_is_the_plain_gate(self, engine):
+        plain = _outcome(*self._run(engine, **MIXED))
+        same = _outcome(*self._run(engine, battery_resume=0.2, **MIXED))
+        assert plain == same
+
+    @pytest.mark.parametrize("dropout", ["lose", "resume"])
+    def test_every_engine_runs_the_gate_alike(self, dropout):
+        loop, vec, jx = (_outcome(*self._run(e, dropout=dropout, **GATE))
+                         for e in ("loop", "vectorized", "jax"))
+        assert loop == vec == jx
+        levels = np.asarray(loop[2])
+        assert np.array_equal(levels, np.rint(levels))   # whole quanta
+        assert loop[3][0] > 0 and loop[3][2] > 0
+
+    def test_a_held_user_waits_for_the_resume_level(self):
+        """One user on a charger below the resume level stays down until
+        its level passes it, then comes up once."""
+        d = MarkovChurnDynamics(p_off=0.0, p_on=1.0, battery_init=0.14,
+                                battery_min=0.15, battery_resume=0.2,
+                                battery_quantum=1e-4, charge_rate=1e-3)
+        dyn = d.init_state(1)
+        assert dyn["battery"][0] == 1400.0
+        key = np.array([0, 1], np.uint32)
+        mode, corun = np.full(1, 2, np.int8), np.zeros(1, bool)
+        ups = []
+        for _ in range(70):
+            dyn, key, eff = d.host_step(dyn, key, mode, corun, 1.0)
+            ups.append(bool(eff.up[0]))
+        # 10 quanta a slot: 1410, ..., 2000 held, 2010 (slot 61) up
+        assert ups == [False] * 60 + [True] * 10
+        assert int(dyn["recoveries"]) == 1
+
+    def test_the_plain_gate_without_resume_flaps(self):
+        """Without a resume level the same user comes up as soon as it
+        passes battery_min."""
+        d = MarkovChurnDynamics(p_off=0.0, p_on=1.0, battery_init=0.14,
+                                battery_min=0.15, charge_rate=1e-3)
+        dyn, key = d.init_state(1), np.array([0, 1], np.uint32)
+        mode, corun = np.full(1, 2, np.int8), np.zeros(1, bool)
+        ups = []
+        for _ in range(20):
+            dyn, key, eff = d.host_step(dyn, key, mode, corun, 1.0)
+            ups.append(bool(eff.up[0]))
+        assert ups.index(True) < 15
+
+    def test_float32_levels_are_the_float64_levels(self):
+        import jax
+
+        f64 = _outcome(*self._run("jax", **GATE))
+        jax.config.update("jax_enable_x64", False)
+        try:
+            sim, r = self._run("jax", **GATE)
+            assert sim.state.dyn["battery"].dtype == np.float32
+            f32 = _outcome(sim, r)
+        finally:
+            jax.config.update("jax_enable_x64", True)
+        assert f32[2] == f64[2]
+
+    @pytest.mark.parametrize("kw, match", [
+        (dict(battery_min=0.2, battery_resume=0.1), "battery_resume"),
+        (dict(battery_resume=1.0), "battery_resume"),
+        (dict(battery_quantum=0.0), "battery_quantum"),
+        (dict(battery_quantum=3e-4), "whole number"),
+        (dict(battery_quantum=1e-4, battery_min=0.15005), "battery_min"),
+        (dict(battery_quantum=1e-9), "2\\*\\*24"),
+    ])
+    def test_bad_gate_knobs_raise_at_construction(self, kw, match):
+        with pytest.raises(ValueError, match=match):
+            MarkovChurnDynamics(**kw)
+
+    def test_a_step_of_part_of_a_quantum_raises_at_build(self):
+        with pytest.raises(ValueError, match="whole number"):
+            Scenario(dynamics=MarkovChurnDynamics(battery_quantum=1e-4),
+                     **dict(BASE, t_d=0.3)).build()

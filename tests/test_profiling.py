@@ -210,3 +210,39 @@ def test_spans_cost_no_time_with_the_profiler_off():
             pass
     per_span = (time.perf_counter() - t0) / n
     assert per_span < 50e-6
+
+
+@pytest.mark.parametrize("churn", [False, True], ids=["none", "markov"])
+def test_the_finish_carries_the_churn_counters(tmp_path, churn):
+    from repro.core import MarkovChurnDynamics
+
+    dyn = MarkovChurnDynamics(p_off=0.02, p_on=0.1, p_net_bad=0.05) \
+        if churn else "none"
+    sim = fleet(dynamics=dyn, seed=5).build()
+    result, events = traced(tmp_path, sim.run)
+    finish, = named(events, "scan.finish")
+    if not churn:
+        assert finish[3] == {}
+        return
+    counters = {k: getattr(result, k)
+                for k in ("drops", "departures", "recoveries", "lost")}
+    assert finish[3] == counters
+    assert counters["drops"] > 0 and counters["recoveries"] > 0
+
+
+@pytest.mark.parametrize("capacity", [0, 8], ids=["fits", "overflows"])
+def test_the_run_records_the_chunk_it_dispatched_last(tmp_path, capacity):
+    """``sim.scan_chunk`` is the last chunk the run ran: its length and
+    push capacity are those of the last ``scan.chunk`` span, after any
+    overflow, and its HLO text holds that capacity's buffer."""
+    sim = fleet(push_log_capacity=capacity, app_arrival_p=0.05).build()
+    assert sim.scan_chunk is None
+    _, events = traced(tmp_path, sim.run)
+    last = named(events, "scan.chunk")[-1][3]
+    rec = sim.scan_chunk
+    assert (rec.chunk, rec.cap) == (320, last["cap"])
+    assert bool(named(events, "scan.overflow")) == bool(capacity)
+    text = rec.hlo_text()
+    assert f"[{rec.cap + ve._push_block(64)},6]" in text
+    assert not any(isinstance(x, jax.Array)
+                   for x in jax.tree.leaves(rec.operands))

@@ -68,6 +68,8 @@ def _assert_twin(s0, r0, s1, r1):
     assert np.array_equal(r0.trace_H, r1.trace_H)
     assert r0.updates == r1.updates
     assert r0.mean_Q == r1.mean_Q
+    assert (r0.drops, r0.departures, r0.recoveries, r0.lost) == \
+        (r1.drops, r1.departures, r1.recoveries, r1.lost)
     # per-user state: exact, field by field (energy included — the lanes
     # never cross shards, only the scalar TOTAL re-associates)
     for f in ("mode", "cooldown", "app", "train_rem", "energy", "updates",
