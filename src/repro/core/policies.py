@@ -487,13 +487,20 @@ class OnlinePolicy(Policy):
         def commit(sched):
             # sv.repl pins `sched` replicated: it has a sharded consumer
             # in the engine (begin-training), and without the pin GSPMD
-            # propagates that layout back through cumsum/gather/where and
+            # propagates that layout back through cumsum/where and
             # turns the gap_sum below into reassociated shard-local
             # partials + AllReduce (the reduce(all-gather) -> all-reduce
             # rewrite), flipping low bits of the Eq. 16 H update
             sched = sv.repl(sched)
             before = jnp.cumsum(sched) - sched
-            gaps = jnp.where(waiting, jnp.where(sched, gap_vec[before],
+            # a scheduled user's gap is Eq. 4 at lag in_flight + before,
+            # computed elementwise: the same power and products as
+            # gap_vec[before], bit for bit, without a per-user gather
+            # (on a TPU v5e at 10^5 users that gather was most of the
+            # slot step's device time)
+            gap_sched = _jax_gradient_gap(vn, sv.in_flight + before,
+                                          sv.eta, sv.beta)
+            gaps = jnp.where(waiting, jnp.where(sched, gap_sched,
                                                 gap_idle_v), 0.0)
             # sum the LIVE lanes only ([:sv.n] folds to a no-op when the
             # sharded scan hasn't padded the user axis): pad lanes never

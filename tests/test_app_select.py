@@ -1,10 +1,11 @@
 """The jax slot step reads each user's per-user table entry for its
 current app by a select over the table's app columns, not a gather.
 
-Two pins: the lowered chunk has no ``gather`` op under the ``slot.apps``
-scope (unsharded with the push log on and off, and the vmapped sweep
-build), and on a fleet whose app columns all hold distinct values the
-jax engine reproduces the NumPy engine in float64: the per-user state the
+Two pins: the lowered online chunk has no ``gather`` op at all, with the
+push log on and off (the online hook computes its Eq. 4 gap elementwise
+too), and in the vmapped sweep build none that reads a value per user;
+and on a fleet whose app columns all hold distinct values the jax engine
+reproduces the NumPy engine in float64: the per-user state the
 selected entries feed (app and training time left, energy) and the
 schedule bit for bit, the cross-user sums and Eq. 4's gap to rounding."""
 from __future__ import annotations
@@ -71,14 +72,32 @@ BASE = dict(n_users=48, horizon_s=600, seed=5, app_arrival_p=0.02,
 
 @pytest.mark.parametrize("log, batch", [(True, 0), (False, 0), (True, 3)],
                          ids=["log", "nolog", "sweep"])
-def test_no_gather_under_the_apps_scope(log, batch):
+def test_the_online_chunk_has_no_gather(log, batch):
     fn, ops = _chunk_and_operands(
         Scenario(**BASE, collect_push_log=log), batch=batch)
     scopes = gather_scopes(fn.lower(*ops).as_text(debug_info=True))
-    assert not [s for s in scopes if "slot.apps" in s], scopes
-    # the parser does see gathers: the online hook's own lag lookup in
-    # slot.policy is still one (and not this test's business)
-    assert any("slot.policy" in s for s in scopes), scopes
+    if not batch:
+        assert scopes == [], scopes
+    else:
+        # vmap turns a dynamic slice at a per-config index into a gather:
+        # the chunk's slices outside the slot step, the push log's block
+        # windows and the slow replay's scalar lag lookup. None reads a
+        # value per user.
+        assert all("slot." not in s or "slot.push_log" in s
+                   or "slow.<locals>.body" in s for s in scopes), scopes
+
+
+def test_the_parser_sees_a_gather():
+    """An array indexed by an array lowers to a gather, and the parser
+    names it with its scope."""
+    def lookup(table, idx):
+        with jax.named_scope("lookup"):
+            return table[idx]
+
+    text = jax.jit(lookup).lower(
+        jnp.arange(8.0), jnp.array([3, 1, 4])).as_text(debug_info=True)
+    scopes = gather_scopes(text)
+    assert len(scopes) == 1 and "lookup" in scopes[0], scopes
 
 
 def distinct_catalog(n_devices=3):
